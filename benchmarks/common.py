@@ -83,16 +83,32 @@ def train_small_lm(
     return model, params, extra
 
 
-def get_grams(name: str, model, params, n_samples: int = 256, force: bool = False) -> GramStore:
-    path = os.path.join(MODELS_DIR, name, "grams.npz")
-    if not force and os.path.exists(path):
+def gram_cache_key(cfg) -> str:
+    """Cache key of a model's calibration Grams: everything that shapes
+    them (name, depth, widths, vocabulary)."""
+    return (f"{cfg.name}-L{cfg.num_layers}-d{cfg.d_model}"
+            f"-h{cfg.num_heads}x{cfg.num_kv_heads}x{cfg.head_dim}"
+            f"-ff{cfg.d_ff}-v{cfg.vocab_size}")
+
+
+def get_grams(model, params, n_samples: int = 256, force: bool = False,
+              cache: bool = True) -> GramStore:
+    """Calibration Grams of ``model`` on the en_a domain, with tokens drawn
+    from the model's own vocabulary; cached on disk under the config's
+    key unless ``cache`` is False."""
+    cfg = model.cfg
+    path = os.path.join(MODELS_DIR, cfg.name,
+                        f"grams-{gram_cache_key(cfg)}.npz")
+    if cache and not force and os.path.exists(path):
         return GramStore.load(path)
     store = collect_grams(
         model, params,
-        calibration_batches(VOCAB, "en_a", n_samples=n_samples, batch=16, seq=SEQ),
+        calibration_batches(cfg.vocab_size, "en_a", n_samples=n_samples,
+                            batch=16, seq=SEQ),
     )
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    store.save(path)
+    if cache:
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        store.save(path)
     return store
 
 

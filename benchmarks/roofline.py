@@ -27,11 +27,19 @@ from typing import Dict, List, Optional
 
 from repro.configs import SHAPE_CASES, applicable_shapes, get_config
 from repro.configs.registry import ASSIGNED
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import device_peaks
 from repro.models.api import count_active_params
 from repro.models.blocks import resolve_specs
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments", "dryrun")
+
+# The dry-run compiles for a v5e mesh, so this static model prices its
+# cells against that chip's published peaks (never the host it runs on).
+TARGET_KIND = "TPU v5 lite"
+_PEAKS = device_peaks(TARGET_KIND)
+PEAK_FLOPS_BF16 = _PEAKS["flops_bf16"]
+HBM_BW = _PEAKS["hbm_bw"]
+ICI_BW = _PEAKS["ici_bw"]
 
 
 # ------------------------------------------------------- analytic flops

@@ -277,7 +277,7 @@ def run(model_name: str = "small-llama", requests: int = 24, max_new: int = 24,
     per_req = -(-(max(len(p) for p in prompts) + max_new) // block_size)
     num_blocks = max_batch * per_req
 
-    grams = get_grams(model_name, model, params)
+    grams = get_grams(model, params)
     plan = build_plan(
         model.compressible_targets(),
         CompressionConfig(method="nsvd1", ratio=ratio, dtype="float32",

@@ -34,7 +34,7 @@ def run(model_name: str = "small-llama", ratios=RATIOS, methods=METHODS):
             print(fmt_row(f"r={r['ratio']:.0%} {r['method']}", r))
         return cached
     model, params, _ = train_small_lm(model_name)
-    grams = get_grams(model_name, model, params)
+    grams = get_grams(model, params)
     rows: List[dict] = []
     base = baseline_ppl(model, params)
     print(fmt_row("original", base))

@@ -30,7 +30,7 @@ def run(model_name: str = "small-llama"):
             print(fmt_row(f"k1={r['k1_frac']:.2f} ({r['method']})", r))
         return cached
     model, params, _ = train_small_lm(model_name)
-    grams = get_grams(model_name, model, params)
+    grams = get_grams(model, params)
     rows = []
     for k1 in K1_FRACS:
         method = "asvd1" if k1 == 1.0 else "nsvd1"

@@ -23,7 +23,7 @@ def run(model_name: str = "small-llama"):
             print(fmt_row(f"{r['method']} k1={r['k1_frac']:.2f}", r))
         return cached
     model, params, _ = train_small_lm(model_name)
-    grams = get_grams(model_name, model, params)
+    grams = get_grams(model, params)
     rows = []
     base = compress_and_eval(model, params, grams, "asvd1", RATIO)
     rows.append({"k1_frac": 1.0, "method": "asvd1", **base})

@@ -35,7 +35,7 @@ def run():
     rows = []
     for name in FAMILIES:
         model, params, _ = train_small_lm(name)
-        grams = get_grams(name, model, params)
+        grams = get_grams(model, params)
         for method in METHODS:
             ppls = compress_and_eval(model, params, grams, method, RATIO)
             rows.append({"model": name, "method": method, **ppls})

@@ -28,7 +28,7 @@ def main():
     model, params, extra = train_small_lm("small-llama", steps=300)
 
     print("2) collect calibration Grams on the en_a domain (256 samples) ...")
-    grams = get_grams("small-llama", model, params)
+    grams = get_grams(model, params)
 
     print("3) plan NSVD-I compression at 30% parameter removal ...")
     cfg = CompressionConfig(method="nsvd1", ratio=0.3, k1_frac=0.9,

@@ -56,7 +56,7 @@ def drive(model, params, prompts, label, spec_config=None, parallelism=None,
 
 def main():
     model, params, _ = train_small_lm("small-llama", steps=300)
-    grams = get_grams("small-llama", model, params)
+    grams = get_grams(model, params)
 
     cfg = CompressionConfig(method="nsvd1", ratio=0.2, dtype="float32",
                             use_randomized=False)

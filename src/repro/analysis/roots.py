@@ -19,7 +19,6 @@ import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
-import numpy as np
 
 from repro.launch.steps import (
     RootContext,
@@ -65,8 +64,7 @@ def make_root_context(model, *, par=None, max_batch: int = 8,
     follows prefill_pad_safe."""
     dp_shards = 1
     if par is not None and getattr(par, "active", False):
-        dp_size = int(np.prod([par.mesh.shape[a] for a in par.dp_axes]))
-        dp_shards = dp_size if max_batch % dp_size == 0 else 1
+        dp_shards = par.dp_size if max_batch % par.dp_size == 0 else 1
     return RootContext(
         model=model, max_batch=max_batch, max_len=max_len,
         kv_quant=kv_quant, prefill_chunk=prefill_chunk,

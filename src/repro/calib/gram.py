@@ -90,8 +90,8 @@ def accumulate_taps(
                 tap_rows[base] = tap_rows.get(base, 0.0) + float(cnt.sum())
         else:
             g, a, c = gram_update(x)
-            g = np.asarray(g, np.float64)
-            a = np.asarray(a, np.float64)
+            # float32 from the device; the store sums in float64.
+            g, a = np.asarray(g), np.asarray(a)
             if suffix:
                 store.update(f"{base}/{suffix}", g, a, float(c))
             store.update(base, g, a, float(c))

@@ -43,8 +43,10 @@ class GramStore:
 
     def update(self, key: str, gram: np.ndarray, absmean: np.ndarray, count: float):
         if key in self._grams:
-            self._grams[key] = self._grams[key] + gram
-            self._absmean[key] = self._absmean[key] + absmean
+            # In place, upcasting each element: the same float64 sums as
+            # ``acc + float64(gram)`` without two Gram-sized temporaries.
+            np.add(self._grams[key], gram, out=self._grams[key])
+            np.add(self._absmean[key], absmean, out=self._absmean[key])
             self._counts[key] += count
         else:
             self._grams[key] = np.asarray(gram, np.float64).copy()
@@ -205,9 +207,10 @@ def compress_params(
     achieved-vs-requested rank/bytes, Gram fallback usage).  Compressed
     params are bit-identical with telemetry on or off.
     """
-    import copy
-
-    new_params = copy.deepcopy(_to_mutable(params))
+    # Fresh dicts over the same leaves: targets are replaced, never
+    # mutated, so the caller's tree (and its device buffers) stays intact
+    # without a copy.
+    new_params = _to_mutable(params)
     cfg = plan.config
     needs_gram = cfg.method not in ("svd", "plain")
     observing = telemetry is not None and telemetry.enabled

@@ -20,6 +20,7 @@ import dataclasses
 from typing import Optional
 
 import numpy as np
+import scipy.linalg
 
 Array = np.ndarray
 
@@ -74,9 +75,12 @@ def diag_absmean_whitener(absmean: Array, eps: float = 1e-6) -> Whitener:
 
 
 def _tri_inv(l: Array) -> Array:
-    """Inverse of a lower-triangular matrix via back-substitution (no scipy)."""
-    n = l.shape[0]
-    inv = np.linalg.solve(l, np.eye(n, dtype=l.dtype))
+    """Inverse of a lower-triangular matrix (LAPACK trtri: n^3/3 flops,
+    against about 8n^3/3 for a general solve against the identity)."""
+    trtri = scipy.linalg.get_lapack_funcs("trtri", (l,))
+    inv, info = trtri(l, lower=1)
+    if info != 0:
+        raise np.linalg.LinAlgError(f"trtri failed (info={info})")
     return inv
 
 

@@ -21,8 +21,14 @@ Schedule — ROW-PACKED and DOUBLE-BUFFERED:
   * The block table and per-row lengths ride scalar prefetch (SMEM) so page
     DMA source indices are known before any data lands.  Pools stay
     HBM-resident (memory_space=ANY); each pack iteration streams one
-    (block_size, Hkv, hd) page PER PACKED ROW (plus (block_size, Hkv)
-    dequant scales for int8 pools) into VMEM.
+    (block_size, Hkv, hd) page PER PACKED ROW into VMEM.
+  * int8 pools: a (block_size, Hkv) scale page cannot be DMA'd on its own
+    (Mosaic slices HBM only along 128-lane-aligned minor dims), so the
+    wrapper gathers each pack's scales into one lane-major (Hkv, R*bs)
+    tile per page, padded to 128 lanes, and the kernel streams one such
+    tile per pack iteration.  A scale is per (key, head), so dequant is
+    applied to score columns (q.(s k) = s (q.k)) and to probability
+    columns before the PV matmul (sum p (s v) = sum (p s) v).
   * Page DMAs are DOUBLE-BUFFERED: two VMEM slots per operand, the copies
     for page p+1 start before the pack multiplies page p, so the next pages
     stream while the MXU works the current tile.
@@ -63,8 +69,8 @@ def default_rows_per_pack(batch: int, group: int) -> int:
 def _kernel(bt_ref, len_ref, q_ref, kp_ref, vp_ref, *rest, block_size,
             scale, quant, rows_per_pack, max_blocks):
     if quant:
-        (ksp_ref, vsp_ref, o_ref, k_buf, v_buf, ks_buf, vs_buf,
-         sem_k, sem_v, sem_ks, sem_vs) = rest
+        (ksp_ref, vsp_ref, o_ref, k_buf, v_buf, sem_k, sem_v, ks_buf,
+         vs_buf, sem_ks, sem_vs) = rest
     else:
         o_ref, k_buf, v_buf, sem_k, sem_v = rest
     bs = block_size
@@ -78,45 +84,48 @@ def _kernel(bt_ref, len_ref, q_ref, kp_ref, vp_ref, *rest, block_size,
     # that head's packed keys in one (R*G, hd) @ (hd, R*bs) MXU pass.
     qh = jnp.transpose(q, (1, 0, 2, 3)).reshape(hkv, r_pack * g, hd)
 
-    lens = jnp.stack([len_ref[r0 + r] for r in range(r_pack)])  # (R,)
-    n_pages = (jnp.max(lens) + bs - 1) // bs  # pack loop bound
+    lens = [len_ref[r0 + r] for r in range(r_pack)]  # R SMEM scalars
+    n_pages = (functools.reduce(jnp.maximum, lens) + bs - 1) // bs
 
     def dma(buf, pool_ref, sem, slot, r, page):
         return pltpu.make_async_copy(pool_ref.at[page], buf.at[slot, r],
                                      sem.at[slot, r])
 
-    def start_pages(slot, p):
+    def scale_dma(buf, src_ref, sem, slot, pp):
+        return pltpu.make_async_copy(src_ref.at[i, pp], buf.at[slot],
+                                     sem.at[slot])
+
+    def page_copies(slot, p):
         pp = jnp.minimum(p, max_blocks - 1)
         for r in range(r_pack):
             # Clamp freed rows' -1 sentinels (and short rows' exhausted
             # tables) to block 0: the fetch is junk the mask hides.
             page = jnp.maximum(bt_ref[r0 + r, pp], 0)
-            dma(k_buf, kp_ref, sem_k, slot, r, page).start()
-            dma(v_buf, vp_ref, sem_v, slot, r, page).start()
-            if quant:
-                dma(ks_buf, ksp_ref, sem_ks, slot, r, page).start()
-                dma(vs_buf, vsp_ref, sem_vs, slot, r, page).start()
+            yield dma(k_buf, kp_ref, sem_k, slot, r, page)
+            yield dma(v_buf, vp_ref, sem_v, slot, r, page)
+        if quant:
+            yield scale_dma(ks_buf, ksp_ref, sem_ks, slot, pp)
+            yield scale_dma(vs_buf, vsp_ref, sem_vs, slot, pp)
+
+    def start_pages(slot, p):
+        for c in page_copies(slot, p):
+            c.start()
 
     def wait_pages(slot, p):
-        pp = jnp.minimum(p, max_blocks - 1)
-        for r in range(r_pack):
-            page = jnp.maximum(bt_ref[r0 + r, pp], 0)
-            dma(k_buf, kp_ref, sem_k, slot, r, page).wait()
-            dma(v_buf, vp_ref, sem_v, slot, r, page).wait()
-            if quant:
-                dma(ks_buf, ksp_ref, sem_ks, slot, r, page).wait()
-                dma(vs_buf, vsp_ref, sem_vs, slot, r, page).wait()
+        for c in page_copies(slot, p):
+            c.wait()
 
     # Masks of the packed score tile: query n belongs to pack row n // G,
     # key column m to pack row m // bs — only the block diagonal is real.
-    # Per-column row lengths are laid out by broadcast (no vector gather).
+    # Per-column row lengths are selected from the SMEM scalars per pack
+    # row (Mosaic has no (R, bs) -> (1, R*bs) vector reshape).
     rq = jax.lax.broadcasted_iota(jnp.int32, (r_pack * g, 1), 0) // g
     rc = jax.lax.broadcasted_iota(jnp.int32, (1, r_pack * bs), 1) // bs
     same_row = rq == rc                                    # (R*G, R*bs)
     key_off = jax.lax.broadcasted_iota(jnp.int32, (1, r_pack * bs), 1) % bs
-    len_cols = jnp.broadcast_to(
-        lens[:, None], (r_pack, bs)
-    ).reshape(1, r_pack * bs)
+    len_cols = jnp.zeros((1, r_pack * bs), jnp.int32)
+    for r in range(r_pack):
+        len_cols = jnp.where(rc == r, lens[r], len_cols)
 
     @pl.when(n_pages > 0)
     def _warmup():
@@ -133,15 +142,14 @@ def _kernel(bt_ref, len_ref, q_ref, kp_ref, vp_ref, *rest, block_size,
         wait_pages(slot, p)
         k = k_buf[slot].astype(jnp.float32)  # (R, bs, Hkv, hd)
         v = v_buf[slot].astype(jnp.float32)
-        if quant:
-            k = k * ks_buf[slot][..., None]
-            v = v * vs_buf[slot][..., None]
         # (Hkv, R*bs, hd): packed-key layout matching qh.
         kh = jnp.transpose(k, (2, 0, 1, 3)).reshape(hkv, r_pack * bs, hd)
         vh = jnp.transpose(v, (2, 0, 1, 3)).reshape(hkv, r_pack * bs, hd)
         s = jnp.einsum("knd,kmd->knm", qh, kh,
                        preferred_element_type=jnp.float32)
         s = s * scale
+        if quant:  # per-(key, head) dequant scales, (Hkv, 1, R*bs)
+            s = s * jnp.expand_dims(ks_buf[slot, :, : r_pack * bs], 1)
         pos = p * bs + key_off
         valid = jnp.logical_and(same_row, pos < len_cols)  # (R*G, R*bs)
         s = jnp.where(valid[None], s, NEG_INF)
@@ -149,8 +157,10 @@ def _kernel(bt_ref, len_ref, q_ref, kp_ref, vp_ref, *rest, block_size,
         pexp = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(pexp, axis=-1, keepdims=True)
+        pv = (pexp * jnp.expand_dims(vs_buf[slot, :, : r_pack * bs], 1)
+              if quant else pexp)
         acc_new = acc * corr + jnp.einsum(
-            "knm,kmd->knd", pexp, vh, preferred_element_type=jnp.float32
+            "knm,kmd->knd", pv, vh, preferred_element_type=jnp.float32
         )
         return acc_new, m_new, l_new
 
@@ -162,6 +172,25 @@ def _kernel(bt_ref, len_ref, q_ref, kp_ref, vp_ref, *rest, block_size,
     o_ref[...] = jnp.transpose(
         out.reshape(hkv, r_pack, g, hd), (1, 0, 2, 3)
     ).astype(o_ref.dtype)
+
+
+def scale_lanes(rows_per_pack: int, block_size: int) -> int:
+    """Lane width of one pack's per-page scale tile (R*bs, padded to the
+    128-lane tile so Mosaic can slice it out of HBM)."""
+    return -(-rows_per_pack * block_size // 128) * 128
+
+
+def _pack_scales(scales, block_tables, r_pack, lanes):
+    """(N, bs, Hkv) scale pool -> (B/R, M, Hkv, lanes): for each pack and
+    page, the packed rows' scales in the kernel's key-column order
+    (column r*bs + t), zero-padded to ``lanes``."""
+    b, m = block_tables.shape
+    _, bs, hkv = scales.shape
+    g = scales[jnp.maximum(block_tables, 0)]  # (B, M, bs, Hkv)
+    g = jnp.transpose(g.reshape(b // r_pack, r_pack, m, bs, hkv),
+                      (0, 2, 4, 1, 3)).reshape(b // r_pack, m, hkv,
+                                               r_pack * bs)
+    return jnp.pad(g, ((0, 0), (0, 0), (0, 0), (0, lanes - r_pack * bs)))
 
 
 @functools.partial(
@@ -212,25 +241,25 @@ def paged_attention(
     )
     in_specs = [
         pl.BlockSpec((r_pack, hkv, g, hd), lambda i, bt, ln: (i, 0, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     scratch = [  # double-buffered (2 slots) per-row page tiles
         pltpu.VMEM((2, r_pack, bs, hkv, hd), k_pages.dtype),
         pltpu.VMEM((2, r_pack, bs, hkv, hd), v_pages.dtype),
     ]
     operands = [block_tables, lengths, qg, k_pages, v_pages]
+    scratch += [pltpu.SemaphoreType.DMA((2, r_pack))] * 2
     if quant:
+        lanes = scale_lanes(r_pack, bs)
         in_specs += [
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ]
-        scratch += [
-            pltpu.VMEM((2, r_pack, bs, hkv), jnp.float32),
-            pltpu.VMEM((2, r_pack, bs, hkv), jnp.float32),
-        ]
-        operands += [k_scales, v_scales]
-    scratch += [pltpu.SemaphoreType.DMA((2, r_pack))] * (4 if quant else 2)
+        scratch += [pltpu.VMEM((2, hkv, lanes), jnp.float32)] * 2
+        scratch += [pltpu.SemaphoreType.DMA((2,))] * 2
+        operands += [_pack_scales(sc, block_tables, r_pack, lanes)
+                     for sc in (k_scales, v_scales)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -282,10 +311,11 @@ def vmem_tiles(batch: int, num_q_heads: int, num_kv_heads: int,
          "buffers": 1},
     ]
     if quant:
+        lanes = scale_lanes(r, block_size)
         tiles += [
-            {"name": "k_scale_ring", "shape": (2, r, block_size, hkv),
+            {"name": "k_scale_ring", "shape": (2, hkv, lanes),
              "dtype": "float32", "buffers": 1},
-            {"name": "v_scale_ring", "shape": (2, r, block_size, hkv),
+            {"name": "v_scale_ring", "shape": (2, hkv, lanes),
              "dtype": "float32", "buffers": 1},
         ]
     return tiles

@@ -1,12 +1,15 @@
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any other import (jax locks the device
-count at first init).  512 host-platform devices back both the single-pod
-(16 x 16 = 256) and multi-pod (2 x 16 x 16 = 512) production meshes.
+The lines above MUST run before any other import (jax locks the platform
+and device count at first init).  The tool is compile-only and pinned to
+the CPU platform, so it never takes an attached accelerator.  512
+host-platform devices back both the single-pod (16 x 16 = 256) and
+multi-pod (2 x 16 x 16 = 512) production meshes.
 
 Per cell this driver:
   1. builds the model against the production mesh,

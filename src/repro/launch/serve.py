@@ -19,21 +19,46 @@ to a multiple of ``--dp`` so every shard holds an equal sub-pool."""
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Any, Dict, List, NamedTuple
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.models import build_model
 from repro.serving.engine import ServingEngine
 
 
-def main():
+class Served(NamedTuple):
+    """What one ``serve`` call leaves behind: the (closed) engine, its
+    token streams by uid, the params it served, the model and its config,
+    and host seconds per phase (calibrate / compress / serve; serve
+    includes the first compile of every root)."""
+
+    engine: ServingEngine
+    outputs: Dict[int, List[int]]
+    params: Any
+    model: Any
+    cfg: ModelConfig
+    seconds: Dict[str, float]
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="small-llama")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the arch's smoke-size variant (--no-reduced "
+                         "keeps the published widths)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut depth to N layers, keeping every width")
     ap.add_argument("--requests", type=int, default=8)
+    ap.add_argument("--prompt-len", type=int, default=8,
+                    help="tokens per seeded random prompt")
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=256)
@@ -90,8 +115,8 @@ def main():
                          "devices (factored NSVD layers all-reduce rank-k "
                          "partials, so TP collectives shrink with "
                          "compression). dp*tp must fit jax.device_count() "
-                         "or the mesh falls back to (1,1) with a warning; "
-                         "use XLA_FLAGS=--xla_force_host_platform_device_"
+                         "(the run refuses to start otherwise); use "
+                         "XLA_FLAGS=--xla_force_host_platform_device_"
                          "count=N to emulate on CPU")
     ap.add_argument("--audit", action="store_true",
                     help="preflight the static contract auditor "
@@ -145,19 +170,40 @@ def main():
                             "--profile-steps decode steps into DIR")
     obs_g.add_argument("--profile-steps", type=int, default=8,
                        help="steps to profile with --profile-dir")
-    args = ap.parse_args()
+    return ap
 
+
+def model_config(args) -> ModelConfig:
+    """The registry config of ``--arch``, reduced and/or depth-cut as
+    asked (``--layers`` keeps every width)."""
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    return cfg
+
+
+def load_model(args):
+    """(model, params): a trained small LM, or ``model_config(args)`` with
+    seeded random weights."""
     if args.arch.startswith("small-"):
         from benchmarks.common import train_small_lm
 
         model, params, _ = train_small_lm(args.arch)
-        cfg = model.cfg
-    else:
-        cfg = get_config(args.arch)
-        if args.reduced:
-            cfg = cfg.reduced()
-        model = build_model(cfg)
-        params = model.init(jax.random.key(0))
+        return model, params
+    model = build_model(model_config(args))
+    return model, model.init(jax.random.key(args.seed))
+
+
+def serve(args) -> Served:
+    """The CLI's whole path for parsed ``args``: load or build the model,
+    calibrate + NSVD-compress when asked, then serve ``--requests``
+    seeded prompts through one ``ServingEngine``."""
+    enable_compilation_cache()
+    seconds: Dict[str, float] = {}
+    model, params = load_model(args)
+    cfg = model.cfg
 
     # Observability comes up BEFORE compression so the compression pass
     # (when --compress rides along) reports into the same registry the
@@ -186,27 +232,38 @@ def main():
                   "(+ /metrics.json, /healthz)")
 
     base_params = params
-    if args.compress is not None:
+    grams = None
+    if args.compress is not None or args.spec_ratio is not None:
         from benchmarks.common import get_grams
+
+        t0 = time.perf_counter()
+        # Trained small LMs keep their Grams on disk; a seeded random-
+        # weight model recalibrates (cheap next to GBs of f64 Grams).
+        grams = get_grams(model, params,
+                          cache=args.arch.startswith("small-"))
+        seconds["calibrate"] = time.perf_counter() - t0
+    if args.compress is not None:
         from repro.core import CompressionConfig, build_plan, compress_params
 
-        grams = get_grams(args.arch, model, params)
+        t0 = time.perf_counter()
+        # Factorization math runs in float64 on the host; the factors are
+        # stored in the model's own dtype.  use_randomized lets best_svd
+        # take the randomized SVD only where a matrix is large enough.
         plan = build_plan(
             model.compressible_targets(),
             CompressionConfig(method="nsvd1", ratio=args.compress,
-                              dtype="float32", use_randomized=False),
+                              dtype=cfg.dtype, use_randomized=True),
         )
         params = compress_params(base_params, plan, grams,
                                  telemetry=compress_telemetry)
+        seconds["compress"] = time.perf_counter() - t0
         print(f"serving NSVD-compressed weights ({plan.achieved_ratio:.0%} removed)")
 
     spec_config = None
     if args.spec_ratio is not None:
-        from benchmarks.common import get_grams
         from repro.models.api import build_draft_params
         from repro.serving.spec import SpecConfig
 
-        grams = get_grams(args.arch, model, base_params)
         draft_params = build_draft_params(model, base_params, grams,
                                           args.spec_ratio)
         spec_config = SpecConfig(draft_params=draft_params, k=args.spec_k,
@@ -288,30 +345,32 @@ def main():
     signal.signal(signal.SIGTERM, lambda *_: eng.request_drain())
     rng = np.random.default_rng(args.seed)
     for _ in range(args.requests):
-        eng.submit(rng.integers(2, cfg.vocab_size // 2, size=8),
+        eng.submit(rng.integers(2, cfg.vocab_size // 2, size=args.prompt_len),
                    max_new_tokens=args.max_new,
                    temperature=args.temperature)
-    t0 = time.time()
+    t0 = time.perf_counter()
     # The metrics server thread must come down with the engine, crash or
     # clean exit alike — a daemon thread holding the port would outlive a
     # failed run in long-lived launchers.
     try:
-        out = eng.run()
+        try:
+            out = eng.run()
+        finally:
+            # Idempotent engine teardown: sheds anything still queued/
+            # parked and retires live rows with finish_reason='shutdown'
+            # (a no-op after a clean run).  Its errors surface; on a failed
+            # run they chain onto the run's own error.
+            eng.close()
     except BaseException:
         if metrics_server is not None:
             metrics_server.close()
         raise
-    finally:
-        # Idempotent engine teardown: sheds anything still queued/parked
-        # and retires live rows with finish_reason='shutdown' (a no-op
-        # after a clean run; best-effort when unwinding a ServingFault).
-        try:
-            eng.close()
-        except Exception:
-            pass
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
+    seconds["serve"] = dt
     n = sum(len(v) for v in out.values())
-    print(f"{len(out)} requests, {n} tokens, {n/dt:.1f} tok/s")
+    dev = jax.devices()[0]
+    print(f"{len(out)} requests, {n} tokens in {dt:.2f}s host time "
+          f"(includes compile) on {dev.platform}/{dev.device_kind}")
     s = eng.stats()
     if s.get("steps"):
         print(f"decode steps: {s['steps']} (pipeline depth "
@@ -386,6 +445,11 @@ def main():
             print(f"jax.profiler trace -> {args.profile_dir}")
         if metrics_server is not None:
             metrics_server.close()
+    return Served(eng, out, params, model, cfg, seconds)
+
+
+def main():
+    serve(build_parser().parse_args())
 
 
 if __name__ == "__main__":

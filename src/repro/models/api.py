@@ -193,7 +193,6 @@ def serving_cache_pspecs(model: Model, par: Parallelism, *,
     ``axes``/``shapes``: optional precomputed axis tree + cache (shape)
     tree — callers that already probed (PagedKVCache keeps its block axes)
     pass them to skip re-tracing the cache init."""
-    import numpy as _np
     from jax.sharding import PartitionSpec as _P
 
     if (num_blocks is None) == (max_batch is None):
@@ -213,9 +212,7 @@ def serving_cache_pspecs(model: Model, par: Parallelism, *,
             if num_blocks is not None else
             (lambda: model.init_cache(max_batch, max_len,
                                       kv_quant=kv_quant)))
-    dp_size = 1
-    if par.mesh is not None:
-        dp_size = int(_np.prod([par.mesh.shape[a] for a in par.dp_axes]))
+    dp_size = par.dp_size
 
     def spec(leaf, ax):
         entries = [None] * len(leaf.shape)

@@ -23,7 +23,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.parallel.sharding import NONE_PARALLEL, Parallelism, param_pspecs
+from repro.parallel.sharding import (
+    NONE_PARALLEL,
+    Parallelism,
+    param_pspecs,
+    tracing_with,
+)
 
 from . import attention as attn_mod
 from . import mamba as mamba_mod
@@ -187,13 +192,13 @@ def _moe_ffn(params_moe, h, cfg, par: Parallelism, taps, tp):
     # batch=1 long-context cells can't shard batch over DP — replicate
     # (each data shard redundantly routes the single row; EP still splits
     # the expert compute over the model axis).
-    dp_size = 1
-    for a in par.dp_axes:
-        dp_size *= par.mesh.shape[a]
-    x_spec = P(par.dp, None, None) if h.shape[0] % dp_size == 0 else P(None, None, None)
+    x_spec = (P(par.dp, None, None) if h.shape[0] % par.dp_size == 0
+              else P(None, None, None))
 
     def inner(p, xx):
-        out, aux = moe_mod.moe_apply(p, xx, cfg, ep_axis=par.tp_axis)
+        # Per-device code: kernels inside need no partitioning of their own.
+        with tracing_with(NONE_PARALLEL):
+            out, aux = moe_mod.moe_apply(p, xx, cfg, ep_axis=par.tp_axis)
         aux = jax.lax.pmean(aux, par.dp_axes)
         return out, aux
 

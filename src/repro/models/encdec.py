@@ -13,7 +13,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.parallel.sharding import NONE_PARALLEL, Parallelism
+from repro.parallel.sharding import (
+    NONE_PARALLEL,
+    Parallelism,
+    traces_with_own_parallelism,
+)
 
 from .blocks import StackGroup, group_apply, group_cache_init, group_init
 from .layers import (
@@ -81,6 +85,7 @@ class EncDecLM:
         )
         return norm_apply(params["enc_norm"], x)
 
+    @traces_with_own_parallelism
     def apply(
         self,
         params: Mapping[str, Any],

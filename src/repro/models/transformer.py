@@ -8,7 +8,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.parallel.sharding import NONE_PARALLEL, Parallelism
+from repro.parallel.sharding import (
+    NONE_PARALLEL,
+    Parallelism,
+    traces_with_own_parallelism,
+)
 
 from .blocks import (
     group_apply,
@@ -110,6 +114,7 @@ class DecoderLM:
 
     # -------------------------------------------------------------- apply
 
+    @traces_with_own_parallelism
     def apply(
         self,
         params: Mapping[str, Any],

@@ -20,17 +20,14 @@ Two instrumentation layers, split by where they run:
 ``ProfileCapture`` drives ``jax.profiler.start_trace/stop_trace`` from the
 engine's step hooks: capture begins at the first dispatched step and ends
 after N steps have been consumed (so the captured window holds N complete
-dispatch->sync step cycles), degrading to a no-op if the backend's
-profiler is unavailable."""
+dispatch->sync step cycles).  A trace that was asked for and cannot
+start raises: a run never silently drops its profile."""
 
 from __future__ import annotations
 
-import contextlib
 import functools
 
 import jax
-
-_NULL = contextlib.nullcontext()
 
 
 def wrap_root(fn, name: str):
@@ -49,11 +46,8 @@ def wrap_root(fn, name: str):
 
 
 def annotation(name: str):
-    """Host-side profiler span (nullcontext if TraceAnnotation is absent)."""
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return _NULL
+    """Host-side profiler span."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 class ProfileCapture:
@@ -63,8 +57,8 @@ class ProfileCapture:
     The engine calls ``tick_dispatch()`` before each root dispatch and
     ``tick_consume()`` after each consumed step; the capture starts on the
     first dispatch and stops once ``n_steps`` steps have been consumed.
-    Failures (no profiler backend, double-start) disable the capture
-    rather than sinking the serving loop."""
+    A failure to start or stop the trace (no profiler backend, a trace
+    already running) propagates."""
 
     def __init__(self, profile_dir: str, n_steps: int = 8):
         if n_steps < 1:
@@ -78,11 +72,8 @@ class ProfileCapture:
     def tick_dispatch(self) -> None:
         if self.started or self.finished:
             return
-        try:
-            jax.profiler.start_trace(self.profile_dir)
-            self.started = True
-        except Exception:
-            self.finished = True  # profiler unavailable: never retry
+        jax.profiler.start_trace(self.profile_dir)
+        self.started = True
 
     def tick_consume(self) -> None:
         if not self.started or self.finished:
@@ -92,9 +83,7 @@ class ProfileCapture:
             self.stop()
 
     def stop(self) -> None:
-        if self.started and not self.finished:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        running = self.started and not self.finished
         self.finished = True
+        if running:
+            jax.profiler.stop_trace()

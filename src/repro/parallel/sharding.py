@@ -31,7 +31,10 @@ their largest replicated dim — see repro/optim/adamw.py.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
+import functools
 import re
 from typing import Any, Dict, Mapping, Optional, Sequence, Tuple
 
@@ -55,6 +58,19 @@ class Parallelism:
     def dp(self):  # spec entry for batch dims
         return self.dp_axes if len(self.dp_axes) > 1 else self.dp_axes[0]
 
+    @property
+    def dp_size(self) -> int:
+        n = 1
+        for a in self.dp_axes if self.active else ():
+            n *= self.mesh.shape[a]
+        return n
+
+    @property
+    def tp_size(self) -> int:
+        if not self.active or self.tp_axis is None:
+            return 1
+        return self.mesh.shape[self.tp_axis]
+
     def constrain(self, x: jax.Array, *spec) -> jax.Array:
         if not self.active:
             return x
@@ -64,6 +80,39 @@ class Parallelism:
 
 
 NONE_PARALLEL = Parallelism()
+
+# The Parallelism whose mesh XLA partitions the computation being traced,
+# set by the model facades around ``apply``.  Mosaic (Pallas TPU) kernels
+# cannot be partitioned automatically: their ops wrappers read this to run
+# the kernel per device in a shard_map, or to take the XLA path where the
+# kernel has no per-device form.
+_TRACING = contextvars.ContextVar("tracing_parallelism",
+                                  default=NONE_PARALLEL)
+
+
+@contextlib.contextmanager
+def tracing_with(par: Parallelism):
+    token = _TRACING.set(par)
+    try:
+        yield
+    finally:
+        _TRACING.reset(token)
+
+
+def tracing_parallelism() -> Parallelism:
+    return _TRACING.get()
+
+
+def traces_with_own_parallelism(apply):
+    """Decorator for a model facade's ``apply``: trace it under the
+    facade's own ``par`` (see ``tracing_parallelism``)."""
+
+    @functools.wraps(apply)
+    def wrapped(self, *args, **kwargs):
+        with tracing_with(self.par):
+            return apply(self, *args, **kwargs)
+
+    return wrapped
 
 
 def make_parallelism(mesh: Optional[Mesh]) -> Parallelism:

@@ -309,7 +309,7 @@ class ServingEngine:
             # every root; params/caches are plain pytrees, so the rebuilt
             # facade is interchangeable with the caller's.
             model = build_model(model.cfg, par)
-            dp_size = int(np.prod([par.mesh.shape[a] for a in par.dp_axes]))
+            dp_size = par.dp_size
             # Slots (and with them the paged pools' block ranges) shard
             # over DP only when they divide it; otherwise per-slot state
             # and the cache stay replicated while weights keep TP.
@@ -1747,12 +1747,12 @@ class ServingEngine:
             host_keep, temps, eos = self._host_inputs()
             k_row = self._k_row_dev
 
-            try:
-                with self.obs.span("serving.dispatch.spec_draft"):
-                    if self._take_fault("draft_kill") is not None:
-                        # Raised BEFORE the root call, so no draft buffer
-                        # has been donated — engine state is untouched.
-                        raise RuntimeError("injected draft dispatch kill")
+            with self.obs.span("serving.dispatch.spec_draft"):
+                # An injected kill fires BEFORE the root call, so no draft
+                # buffer has been donated — engine state is untouched.  A
+                # real draft-root error propagates like any other.
+                killed = self._take_fault("draft_kill") is not None
+                if not killed:
                     (proposals, q_probs, self.draft.pools,
                      self.draft.key_data) = self._spec_draft(
                         self.draft.params, self.draft.pools,
@@ -1760,10 +1760,10 @@ class ServingEngine:
                         self.last_token, self.cache_len, self.draft.key_data,
                         self._active_dev, host_keep, temps,
                     )
-            except Exception:
-                # Draft path died: degrade to plain decode (greedy streams
-                # are token-identical — verify was always an exact argmax
-                # prefix check) and re-enable after the cool-down.
+            if killed:
+                # Degrade to plain decode (greedy streams are token-
+                # identical — verify was always an exact argmax prefix
+                # check) and re-enable after the cool-down.
                 self._degrade_draft()
                 self._dispatch_decode()
                 return

@@ -38,9 +38,10 @@ Fault kinds
     Sleep ``delay_s`` before the next D2H sync at/after ``step``,
     simulating a hung transfer; the watchdog flags it.
 ``draft_kill``
-    Raise inside the next speculative draft dispatch at/after ``step``;
-    the engine degrades to plain decode and re-enables the draft after
-    a cool-down.
+    Kill the next speculative draft dispatch at/after ``step`` before
+    its root runs; the engine degrades to plain decode and re-enables the
+    draft after a cool-down.  A real draft-root error is not a fault
+    kind: it propagates.
 """
 
 from __future__ import annotations

@@ -380,6 +380,24 @@ class TestSpecDegradation:
         assert fs["draft_reenables"] == 1
         assert not eng.degraded_components()      # healthy again at exit
 
+    def test_real_draft_root_error_propagates(self, tiny_lm):
+        """Only the injected draft kill degrades: an exception raised by
+        the draft root itself (say, a kernel that fails to compile) is a
+        failure of the run, never a silent switch to plain decode."""
+        model, params = tiny_lm
+        eng = ServingEngine(model, params, max_batch=3, max_len=64,
+                            spec_config=self._spec_cfg(params))
+
+        def broken_root(*args):
+            raise ValueError("draft root failed to compile")
+
+        eng._spec_draft = broken_root
+        for p in _prompts(32, 2):
+            eng.submit(p, max_new_tokens=8)
+        with pytest.raises(ValueError, match="failed to compile"):
+            eng.run()
+        assert eng.fault_stats()["draft_kills"] == 0
+
     def test_spec_poison_quarantines_target_only(self, tiny_lm):
         model, params = tiny_lm
         prompts = _prompts(31, 3)

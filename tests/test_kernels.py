@@ -159,3 +159,21 @@ def test_gram_kernel_vs_calibration_update(rows):
     g_kernel = gram_accumulate(x, block_n=32, block_t=64, interpret=True)
     g_runner, _, _ = gram_update(x)
     np.testing.assert_allclose(np.asarray(g_kernel), np.asarray(g_runner), rtol=1e-4, atol=1e-4)
+
+
+def test_count_routes_tallies_each_traced_call_site():
+    """The tally a caller opens records which path every call site took:
+    the XLA oracle by default off-TPU, the kernel when forced."""
+    from repro.kernels.routes import count_routes
+
+    rng = np.random.default_rng(0)
+    x, u, v, u2, v2 = (jnp.asarray(rng.standard_normal(s), jnp.float32)
+                       for s in ((8, 32), (32, 8), (8, 128), (32, 4),
+                                 (4, 128)))
+    with count_routes() as tally:
+        nested_lowrank_matmul(x, u, v, u2, v2)
+        nested_lowrank_matmul(x, u, v, u2, v2, interpret=True)
+    assert tally == {("nested_lowrank", "xla"): 1,
+                     ("nested_lowrank", "pallas"): 1}
+    nested_lowrank_matmul(x, u, v, u2, v2)  # outside: nothing to tally
+    assert sum(tally.values()) == 2

@@ -131,3 +131,116 @@ class TestRankBudget:
         dense = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
         comp = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(cshapes))
         assert comp < 0.78 * dense  # ~30% removed from the compressible set
+
+
+class TestServeCLI:
+    def test_reduced_is_a_switch_and_layers_cut_depth_only(self):
+        from repro.launch.serve import build_parser, model_config
+
+        ap = build_parser()
+        assert model_config(ap.parse_args(["--arch", "mistral-7b"])) == \
+            get_config("mistral-7b").reduced()
+        full = get_config("mistral-7b")
+        cfg = model_config(ap.parse_args(
+            ["--arch", "mistral-7b", "--no-reduced", "--layers", "2"]))
+        assert cfg.num_layers == 2
+        assert cfg == full.__class__(**{**full.__dict__, "num_layers": 2})
+
+    def test_serve_returns_engine_outputs_and_phase_seconds(self, tmp_path,
+                                                            monkeypatch):
+        """The CLI path in-process: compress into the model's own dtype,
+        serve, hand back what was served."""
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from repro.launch.serve import build_parser, serve
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        was = jax.config.jax_compilation_cache_dir
+        try:
+            served = serve(build_parser().parse_args(
+                ["--arch", "mistral-7b", "--layers", "1", "--requests", "2",
+                 "--prompt-len", "5", "--max-new", "3", "--max-len", "32",
+                 "--compress", "0.5"]))
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+            compilation_cache.reset_cache()
+        assert sorted(len(v) for v in served.outputs.values()) == [3, 3]
+        assert served.cfg.num_layers == 1
+        assert {"calibrate", "compress", "serve"} <= set(served.seconds)
+        wq = served.params["g0"]["sub0"]["attn"]["wq"]
+        assert {"u", "v"} <= set(wq) and "kernel" not in wq
+        assert wq["u"].dtype == jnp.dtype(served.cfg.dtype)
+
+
+class TestCompilationCache:
+    @pytest.mark.parametrize("from_env", [True, False],
+                             ids=["env", "in-checkout"])
+    def test_cache_dir(self, tmp_path, monkeypatch, from_env):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from repro.launch.compile_cache import (
+            DEFAULT_DIR,
+            ENV_VAR,
+            enable_compilation_cache,
+        )
+
+        if from_env:
+            monkeypatch.setenv(ENV_VAR, str(tmp_path))
+        else:
+            monkeypatch.delenv(ENV_VAR, raising=False)
+        was = jax.config.jax_compilation_cache_dir
+        try:
+            got = enable_compilation_cache()
+            assert got == jax.config.jax_compilation_cache_dir
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+            compilation_cache.reset_cache()
+        want = str(tmp_path) if from_env else str(DEFAULT_DIR)
+        assert got == want
+        # A fixed directory at the root of the checkout.
+        assert DEFAULT_DIR.name == ".jax_cache"
+        assert (DEFAULT_DIR.parent / "src" / "repro").is_dir()
+
+
+class TestDevicePeaks:
+    def test_v5e_peaks_by_device_kind(self):
+        from repro.launch.mesh import device_peaks
+
+        p = device_peaks("TPU v5 lite")
+        assert p["flops_bf16"] == 197e12 and p["hbm_bw"] == 819e9
+
+    def test_unknown_kind_raises(self):
+        from repro.launch.mesh import device_peaks
+
+        with pytest.raises(KeyError, match="no published peaks"):
+            device_peaks("cpu")
+
+
+class TestGramCache:
+    def test_key_tracks_depth_widths_and_vocab(self):
+        import dataclasses
+
+        from benchmarks.common import gram_cache_key
+
+        cfg = get_config("mistral-7b")
+        keys = {gram_cache_key(c) for c in (
+            cfg, dataclasses.replace(cfg, num_layers=2),
+            dataclasses.replace(cfg, d_ff=1024),
+            dataclasses.replace(cfg, vocab_size=512))}
+        assert len(keys) == 4
+
+    def test_calibration_draws_from_the_model_vocabulary(self, monkeypatch):
+        import benchmarks.common as common
+        from repro.models import build_model
+
+        cfg = get_config("mistral-7b").reduced()
+        seen = []
+
+        def fake_collect(model, params, batches):
+            seen.extend(int(b["tokens"].max()) for b in batches)
+            return "store"
+
+        monkeypatch.setattr(common, "collect_grams", fake_collect)
+        assert common.get_grams(build_model(cfg), None, n_samples=64,
+                                cache=False) == "store"
+        assert seen and max(seen) < cfg.vocab_size

@@ -500,3 +500,17 @@ class TestInstrumentedRoots:
             tel.profile.stop()
         files = list(prof_dir.rglob("*")) if prof_dir.exists() else []
         assert any(f.is_file() for f in files), "no profiler artifacts"
+
+    def test_profile_capture_raises_when_trace_cannot_start(self, tmp_path):
+        """A requested profile that cannot start (here: another trace is
+        already running) raises instead of being silently dropped."""
+        from repro.obs import ProfileCapture
+
+        jax.profiler.start_trace(str(tmp_path / "outer"))
+        try:
+            cap = ProfileCapture(str(tmp_path / "inner"), n_steps=1)
+            with pytest.raises(RuntimeError, match="already been started"):
+                cap.tick_dispatch()
+            assert not cap.started
+        finally:
+            jax.profiler.stop_trace()

@@ -477,17 +477,20 @@ class TestDeviceEOS:
         rng = np.random.default_rng(11)
         p = rng.integers(2, 200, size=7)
         full = _solo(model, params, p, 8, paged=paged)
-        eos = full[2]
+        # The eos token must first occur in the stream at index i >= 1 (a
+        # token seen earlier would stop the row there instead).
+        i = next(j for j in range(1, len(full)) if full[j] not in full[:j])
+        eos = full[i]
 
         eng = ServingEngine(model, params, max_batch=1, max_len=64,
                             paged=paged)
         uid = eng.submit(p, max_new_tokens=8, eos_id=eos)
         out = eng.run()
-        assert out[uid] == full[:3]  # stops at (and includes) the eos token
+        assert out[uid] == full[:i + 1]  # stops at (and includes) eos
         # Device-side exit: the row's active flag was cleared ON DEVICE in
         # the same step that sampled eos, and its cache_len stopped.
         assert not bool(np.asarray(eng._active_dev)[0])
-        assert int(np.asarray(eng.cache_len)[0]) == len(p) + 2
+        assert int(np.asarray(eng.cache_len)[0]) == len(p) + i
 
     def test_eos_row_stops_while_others_continue(self, tiny_lm):
         model, params = tiny_lm

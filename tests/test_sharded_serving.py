@@ -88,9 +88,11 @@ def _serve(model, params, prompts, par=None, max_new=6, temperature=0.0,
 
 class TestMakeServingMesh:
     def test_oversubscribed_mesh_warns_and_falls_back_to_11(self):
-        with pytest.warns(UserWarning, match="falling back"):
-            mesh = make_serving_mesh(jax.device_count() + 1, 1)
-        assert dict(mesh.shape) == {"data": 1, "model": 1}
+        """An oversubscribed mesh no longer degrades to (1, 1): it raises,
+        so a run that asked for dp x tp never silently serves on one
+        device."""
+        with pytest.raises(ValueError, match="needs"):
+            make_serving_mesh(jax.device_count() + 1, 1)
 
     def test_rejects_nonpositive_axes(self):
         with pytest.raises(ValueError, match="positive"):
@@ -421,6 +423,103 @@ class TestSharded22Equivalence:
                             paged=True, num_blocks=4, parallelism=par22)
         with pytest.raises(ValueError, match="shard"):
             eng.submit(np.arange(2, 22), max_new_tokens=13)  # needs 3 > 2
+
+
+# ------------------------------------------- Pallas kernels under a mesh
+
+
+_MESHES = [(1, 1), pytest.param(2, 2, marks=need4),
+           pytest.param(4, 1, marks=need4)]
+
+
+class TestKernelsUnderMesh:
+    """Mosaic kernels cannot be partitioned automatically, so under a mesh
+    the ops wrappers run them per device in a shard_map.  Here the kernels
+    run in interpret mode and must match the unsharded oracle."""
+
+    @pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+    @pytest.mark.parametrize("dp,tp", _MESHES)
+    def test_paged_attention_per_device_matches_oracle(self, dp, tp, quant):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from repro.kernels.paged_attention import ops
+        from repro.parallel.sharding import tracing_with
+
+        par = make_parallelism(make_serving_mesh(dp, tp))
+        b, hq, hkv, hd, bs, n, m = 4, 4, 2, 16, 4, 16, 4
+        rng = np.random.default_rng(dp * 10 + tp)
+        # Like the engine's allocator: each DP shard's rows hold blocks of
+        # that shard's contiguous block range only; -1 pads table tails.
+        per_shard, rows_per_shard = n // dp, b // dp
+        tables = np.full((b, m), -1, np.int32)
+        lengths = rng.integers(1, m * bs + 1, size=b).astype(np.int32)
+        for r in range(b):
+            d = r // rows_per_shard
+            used = -(-int(lengths[r]) // bs)
+            tables[r, :used] = rng.permutation(per_shard)[:used] + d * per_shard
+        pool = (n, bs, hkv, hd)
+        q = rng.standard_normal((b, hq, hd)).astype(np.float32)
+        if quant:
+            kp, vp = (rng.integers(-127, 127, pool).astype(np.int8)
+                      for _ in range(2))
+            scales = tuple(rng.uniform(0.01, 0.05, pool[:3]).astype(
+                np.float32) for _ in range(2))
+        else:
+            kp, vp = (rng.standard_normal(pool).astype(np.float32)
+                      for _ in range(2))
+            scales = ()
+        args = (q, kp, vp, tables, lengths, *scales)
+
+        def placed(a):
+            spec = P("data", *([None] * (a.ndim - 1)))
+            return jax.device_put(a, NamedSharding(par.mesh, spec))
+
+        def step(*a):
+            with tracing_with(par):
+                return ops.paged_attention(*a[:5], *a[5:], interpret=True)
+
+        got = jax.jit(step)(*(placed(a) for a in args))
+        want = ops.paged_attention_ref(*args[:5], *scales)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("dp,tp", _MESHES)
+    def test_nested_lowrank_per_device_matches_oracle(self, dp, tp):
+        from repro.kernels.nested_lowrank import ops
+        from repro.parallel.sharding import tracing_with
+
+        par = make_parallelism(make_serving_mesh(dp, tp))
+        rng = np.random.default_rng(7)
+        x, u, v, u2, v2 = (rng.standard_normal(s).astype(np.float32)
+                           for s in ((8, 64), (64, 24), (24, 96),
+                                     (64, 8), (8, 96)))
+
+        def step(*a):
+            with tracing_with(par):
+                return ops.nested_lowrank_matmul(*a, block_n=32,
+                                                 interpret=True)
+
+        got = jax.jit(step)(x, u, v, u2, v2)
+        want = ops.nested_lowrank_matmul_ref(x, u, v, u2, v2)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
+
+    @need4
+    def test_nested_lowrank_takes_xla_path_under_tensor_parallelism(
+            self, monkeypatch):
+        """Under TP a psum must sit between the two factor matmuls, which
+        the fused kernel cannot hold: the automatic route is XLA even on a
+        TPU, and the kernel where TP is 1."""
+        from repro.kernels.nested_lowrank import ops
+        from repro.parallel.sharding import tracing_with
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        shapes = ((8, 64), np.dtype("float32"), (64, 24), (24, 96), (64, 8))
+        assert ops.takes_kernel(*shapes)
+        with tracing_with(make_parallelism(make_serving_mesh(4, 1))):
+            assert ops.takes_kernel(*shapes)
+        with tracing_with(make_parallelism(make_serving_mesh(2, 2))):
+            assert not ops.takes_kernel(*shapes)
 
 
 # ------------------------------------------------ bench schema migration
