@@ -23,6 +23,7 @@ by tests/test_observability.py)."""
 from __future__ import annotations
 
 import contextlib
+import time
 from typing import Dict, List, Optional
 
 from repro.obs.compression import (
@@ -43,7 +44,7 @@ from repro.obs.metrics import (
     write_metrics_json,
 )
 from repro.obs.profiler import ProfileCapture, annotation, wrap_root
-from repro.obs.trace import PID_ENGINE, PID_REQUESTS, EventTracer
+from repro.obs.trace import PID_ENGINE, PID_REQUESTS, TID_SPANS, EventTracer
 
 __all__ = [
     "Telemetry", "NULL_TELEMETRY", "disabled",
@@ -115,6 +116,11 @@ class Telemetry:
         self.step_sync = m.histogram(
             "serving_step_sync_seconds", "D2H ring-sync stall per consumed "
             "step", buckets=TIME_BUCKETS, window=window)
+        self.step_sync_by_tick = m.histogram(
+            "serving_step_sync_by_tick_seconds", "D2H ring-sync stall per "
+            "consumed step, split by whether a chunk tick was queued ahead "
+            "of the step (tick_ahead)", labelnames=("tick_ahead",),
+            buckets=TIME_BUCKETS, window=window)
         self.step_host = m.histogram(
             "serving_step_host_seconds", "host emission/free bookkeeping "
             "per consumed step", buckets=TIME_BUCKETS, window=window)
@@ -129,6 +135,12 @@ class Telemetry:
             "defrag, dynamic-k, tail flush)")
         self.steps_dispatched = m.counter(
             "serving_steps_dispatched_total", "decode/spec root dispatches")
+        self.prefill_ticks = m.counter(
+            "serving_prefill_ticks_total", "chunked-prefill ticks")
+        self.prefill_tick_fill = m.histogram(
+            "serving_prefill_tick_fill_frac", "prompt tokens / token slots "
+            "per chunked-prefill tick", buckets=FRACTION_BUCKETS,
+            window=window)
 
         # -- paged block pool (per DP shard)
         self.pool_in_use = m.gauge(
@@ -280,14 +292,32 @@ class Telemetry:
         if self.profile is not None:
             self.profile.tick_dispatch()
 
-    def on_step_consume(self, kind: str, sync_s: float,
-                        host_s: float) -> None:
+    def on_step_consume(self, kind: str, sync_s: float, host_s: float,
+                        step_s: float, tick_ahead: bool) -> None:
+        """A consumed step: its sync stall, the host bookkeeping after
+        it, its whole wall time (``ServingEngine.step_times``), and whether
+        a chunk tick was queued on the device ahead of it."""
         self.step_sync.observe(sync_s)
+        self.step_sync_by_tick.labels(
+            tick_ahead=str(tick_ahead).lower()).observe(sync_s)
         self.step_host.observe(host_s)
-        self.tracer.complete(f"sync:{kind}", "step", sync_s, PID_ENGINE, 1)
+        self.tracer.complete(f"sync:{kind}", "step", sync_s, PID_ENGINE, 1,
+                             {"tick_ahead": tick_ahead})
         self.tracer.complete(f"host:{kind}", "step", host_s, PID_ENGINE, 1)
         if self.profile is not None:
             self.profile.tick_consume()
+
+    def on_prefill_tick(self, rows: int, tokens: int, slots: int,
+                        host_s: float) -> None:
+        """One chunked-prefill tick: the prompts it carried, their
+        tokens, the token slots it computed (rows x chunk, padding
+        included) and its host time (wall time less the first-token
+        sync)."""
+        self.prefill_ticks.inc()
+        self.prefill_tick_fill.observe(tokens / slots)
+        self.tracer.complete("tick", "step", host_s, PID_ENGINE, 0,
+                             {"rows": rows, "tokens": tokens,
+                              "slots": slots})
 
     def on_drain(self, n_in_flight: int) -> None:
         self.drains.inc()
@@ -339,9 +369,18 @@ class Telemetry:
         self.tracer.instant("straggler", "fault", PID_ENGINE, 1,
                             {"verdict": verdict, "dur_s": dur_s})
 
+    @contextlib.contextmanager
     def span(self, name: str):
-        """Host-side profiler span around a dispatch/sync region."""
-        return annotation(name)
+        """Host-side span around an engine-loop phase: a profiler
+        annotation, and the same region as an ``X`` event in the tracer
+        (both on the wall clock, so the two exports line up)."""
+        t0 = time.perf_counter()
+        try:
+            with annotation(name):
+                yield
+        finally:
+            self.tracer.complete(name, "span", time.perf_counter() - t0,
+                                 PID_ENGINE, TID_SPANS)
 
     # ------------------------------------------------------------ export
 
@@ -466,7 +505,10 @@ class _NullTelemetry:
                          live_tokens=None, reserved_tokens=None):
         pass
 
-    def on_step_consume(self, kind, sync_s, host_s):
+    def on_step_consume(self, kind, sync_s, host_s, step_s, tick_ahead):
+        pass
+
+    def on_prefill_tick(self, rows, tokens, slots, host_s):
         pass
 
     def on_drain(self, n_in_flight):

@@ -13,9 +13,9 @@ Two instrumentation layers, split by where they run:
     unconditionally: there is no on/off divergence to perturb tokens.
 
   * ``annotation(name)`` — a host-side ``jax.profiler.TraceAnnotation``
-    span for dispatch/sync regions of the ENGINE loop (outside jit).
-    These only mark time on the host timeline while a profiler trace is
-    being captured; they never touch the computation.
+    span for the host phases of the ENGINE loop (``serving.*``, outside
+    jit).  These only mark time on the host timeline while a profiler
+    trace is being captured; they never touch the computation.
 
 ``ProfileCapture`` drives ``jax.profiler.start_trace/stop_trace`` from the
 engine's step hooks: capture begins at the first dispatched step and ends

@@ -11,9 +11,13 @@ oldest events drop, recording never blocks or grows — and export as
     the engine track, request lifecycle as instants ("i") on one track
     per request uid.
 
-Timestamps are ``time.perf_counter`` relative to the tracer's epoch
-(microseconds in the export), so traces from one process line up across
-tracks without wall-clock skew."""
+Timestamps are the wall clock, ``time.time_ns`` (microseconds in the
+export): the clock ``jax.profiler`` stamps its host plane with (an
+``.xplane.pb`` event starts at the ``profile_start_time`` of its "Task
+Environment" plane plus the event's offset).  A span recorded here and
+the profiler annotation of the same region start within a millisecond of
+each other, so the two exports can be laid over one another.  The wall
+clock can step (NTP); durations are measured on ``perf_counter``."""
 
 from __future__ import annotations
 
@@ -26,6 +30,9 @@ from typing import Dict, List, Optional
 # machinery, one for request lifecycles (tid == request uid).
 PID_ENGINE = 0
 PID_REQUESTS = 1
+# Engine-lane threads: 0 dispatches and chunk ticks, 1 syncs and host
+# bookkeeping, 2 the host spans of the engine loop (they nest).
+TID_SPANS = 2
 
 
 class Event:
@@ -63,13 +70,13 @@ class EventTracer:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = capacity
         self._events: deque = deque(maxlen=capacity)
-        self.epoch = time.perf_counter()
         self.total = 0
 
     # ------------------------------------------------------------- record
 
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self.epoch) * 1e6
+    @staticmethod
+    def _now_us() -> float:
+        return time.time_ns() / 1e3
 
     def instant(self, name: str, cat: str, pid: int = PID_ENGINE,
                 tid: int = 0, args: Optional[Dict] = None,

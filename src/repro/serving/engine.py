@@ -247,6 +247,9 @@ class _InFlight:
     dispatch_s: float
     spec: bool = False
     k_row: Optional[np.ndarray] = None
+    # A chunk tick was dispatched after the previous ring entry and its
+    # output was never synced: this step's sync waits for that tick too.
+    tick_ahead: bool = False
 
 
 _PIPELINE_DEPTH_ENV = "REPRO_SERVING_PIPELINE_DEPTH"
@@ -561,6 +564,10 @@ class ServingEngine:
         self.step_device_wait_s: List[float] = []
         self.step_host_s: List[float] = []
         self.decode_transfers = 0
+        # A chunk tick sits on the device queue ahead of the next decode
+        # dispatch: set when a tick is dispatched, cleared by the tick's
+        # first-token sync or by the dispatch that records it.
+        self._tick_unsynced = False
 
     @staticmethod
     def _jit(fn, donate, shardings=None):
@@ -739,11 +746,12 @@ class ServingEngine:
         must be consumed first — the ring is empty while the prefill roots
         run, and no in-flight entry ever straddles a slot's change of
         occupant."""
-        self._drain_ring()
-        finished = self._pop_finished()
-        finished.extend(
-            self._admit_paged() if self.paged else self._admit_dense()
-        )
+        with self.obs.span("serving.admit"):
+            self._drain_ring()
+            finished = self._pop_finished()
+            finished.extend(
+                self._admit_paged() if self.paged else self._admit_dense()
+            )
         return finished
 
     def _obs_finish(self, req: Request) -> None:
@@ -946,80 +954,107 @@ class ServingEngine:
         """Advance every in-flight prefill by ONE chunk (single jit call).
         run() interleaves these ticks with decode steps, so long prompts
         stream in without stalling live rows."""
+        t0 = time.perf_counter()
+        t_sync = 0.0
+        span = self.obs.span
         c = self.prefill_chunk
         r_rows = self.max_batch
         tasks = self._prefilling[:r_rows]
-        tokens = np.zeros((r_rows, c), np.int32)
-        starts = np.zeros((r_rows,), np.int32)
-        nvalid = np.ones((r_rows,), np.int32)
-        fslots = np.full((r_rows,), self.max_batch, np.int32)  # pad = dropped
-        budgets = np.zeros((r_rows,), np.int32)
-        rkeys = np.zeros((r_rows, 2), np.uint32)
-        d_keys = (np.zeros((r_rows, 2), np.uint32)
-                  if self.spec is not None else None)
-        temps = np.zeros((r_rows,), np.float32)
-        bt_rows = np.full((r_rows, self.kv.max_blocks_per_row), -1, np.int32)
-        d_bt = (np.full((r_rows, self.kv.max_blocks_per_row), -1, np.int32)
-                if self.spec is not None else None)
-        fin: List[tuple] = []
-        for r, task in enumerate(tasks):
-            p = task.req.prompt
-            n = min(len(p) - task.pos, c)
-            if self.obs.enabled and task.pos == 0:
-                self.obs.on_first_chunk(task.req.uid, task.slot)
-            tokens[r, :n] = p[task.pos: task.pos + n]
-            starts[r] = task.pos
-            nvalid[r] = n
-            temps[r] = task.req.temperature
-            bt_rows[r] = self.kv.table_np[task.slot]
-            if d_bt is not None:
-                d_bt[r] = self.draft.kv.table_np[task.slot]
-            task.pos += n
-            if task.pos >= len(p):
-                fslots[r] = task.slot
-                # Budget after the first sampled token: fresh requests have
-                # generated == []; a reprefill-resumed request's prompt
-                # already contains its generated tokens, so its budget is
-                # what remains AFTER re-sampling the next one.
-                budgets[r] = max(0, task.req.max_new_tokens
-                                 - len(task.req.generated) - 1)
-                fin.append((r, task))
-        if fin:
-            # Per-request sampling chains for the finishing rows (one
-            # batched fold_in dispatch; see Request/_request_keys).
-            uids = [t.req.uid for _, t in fin]
-            fr = [r for r, _ in fin]
-            rkeys[fr] = self._request_keys(uids)
-            if d_keys is not None:
-                d_keys[fr] = self._request_keys(uids, draft=True)
-        tok_dev, starts_dev = jnp.asarray(tokens), jnp.asarray(starts)
-        fslots_dev = jnp.asarray(fslots)
-        (first, self.kv.pools, self.cache_len, self.last_token,
-         self.budget_dev, self.key_data, self._active_dev) = self._chunk_step(
-            self.params, self.kv.pools, jnp.asarray(bt_rows),
-            tok_dev, starts_dev, jnp.asarray(nvalid),
-            fslots_dev, jnp.asarray(budgets), jnp.asarray(rkeys),
-            self.cache_len, self.last_token, self.budget_dev, self.key_data,
-            jnp.asarray(temps), self._active_dev,
-        )
-        if self.spec is not None:
-            # Stream the same chunk into the draft pools (its own block
-            # tables; lengths/last tokens are shared with the target) and
-            # reset finishing rows' draft keys to their requests' chains.
-            self.draft.pools, self.draft.key_data = self._draft_prefill(
-                self.draft.params, self.draft.pools, jnp.asarray(d_bt),
-                tok_dev, starts_dev, fslots_dev, self.draft.key_data,
-                jnp.asarray(d_keys),
-            )
         finished: List[Request] = []
-        if fin:
-            toks = np.asarray(jax.device_get(first))
-            done_tasks = {id(t) for _, t in fin}
-            for r, task in fin:
-                self._finish_or_activate(task.req, task.slot, int(toks[r]),
-                                         finished)
-            self._prefilling = [t for t in self._prefilling
-                                if id(t) not in done_tasks]
+        with span("serving.prefill_tick"):
+            with span("serving.prefill_tick.build"):
+                tokens = np.zeros((r_rows, c), np.int32)
+                starts = np.zeros((r_rows,), np.int32)
+                nvalid = np.ones((r_rows,), np.int32)
+                # Padding rows name slot max_batch: the root drops them.
+                fslots = np.full((r_rows,), self.max_batch, np.int32)
+                budgets = np.zeros((r_rows,), np.int32)
+                rkeys = np.zeros((r_rows, 2), np.uint32)
+                d_keys = (np.zeros((r_rows, 2), np.uint32)
+                          if self.spec is not None else None)
+                temps = np.zeros((r_rows,), np.float32)
+                bt_rows = np.full((r_rows, self.kv.max_blocks_per_row), -1,
+                                  np.int32)
+                d_bt = (np.full((r_rows, self.kv.max_blocks_per_row), -1,
+                                np.int32)
+                        if self.spec is not None else None)
+                fin: List[tuple] = []
+                for r, task in enumerate(tasks):
+                    p = task.req.prompt
+                    n = min(len(p) - task.pos, c)
+                    if self.obs.enabled and task.pos == 0:
+                        self.obs.on_first_chunk(task.req.uid, task.slot)
+                    tokens[r, :n] = p[task.pos: task.pos + n]
+                    starts[r] = task.pos
+                    nvalid[r] = n
+                    temps[r] = task.req.temperature
+                    bt_rows[r] = self.kv.table_np[task.slot]
+                    if d_bt is not None:
+                        d_bt[r] = self.draft.kv.table_np[task.slot]
+                    task.pos += n
+                    if task.pos >= len(p):
+                        fslots[r] = task.slot
+                        # Budget after the first sampled token: fresh
+                        # requests have generated == []; a reprefill-resumed
+                        # request's prompt already contains its generated
+                        # tokens, so its budget is what remains AFTER
+                        # re-sampling the next one.
+                        budgets[r] = max(0, task.req.max_new_tokens
+                                         - len(task.req.generated) - 1)
+                        fin.append((r, task))
+                if fin:
+                    # Per-request sampling chains for the finishing rows
+                    # (one batched fold_in dispatch; see Request and
+                    # _request_keys).
+                    uids = [t.req.uid for _, t in fin]
+                    fr = [r for r, _ in fin]
+                    with span("serving.request_keys"):
+                        rkeys[fr] = self._request_keys(uids)
+                        if d_keys is not None:
+                            d_keys[fr] = self._request_keys(uids, draft=True)
+            with span("serving.prefill_tick.dispatch"):
+                tok_dev, starts_dev = jnp.asarray(tokens), jnp.asarray(starts)
+                fslots_dev = jnp.asarray(fslots)
+                (first, self.kv.pools, self.cache_len, self.last_token,
+                 self.budget_dev, self.key_data,
+                 self._active_dev) = self._chunk_step(
+                    self.params, self.kv.pools, jnp.asarray(bt_rows),
+                    tok_dev, starts_dev, jnp.asarray(nvalid),
+                    fslots_dev, jnp.asarray(budgets), jnp.asarray(rkeys),
+                    self.cache_len, self.last_token, self.budget_dev,
+                    self.key_data, jnp.asarray(temps), self._active_dev,
+                )
+                if self.spec is not None:
+                    # Stream the same chunk into the draft pools (its own
+                    # block tables; lengths/last tokens are shared with the
+                    # target) and reset finishing rows' draft keys to their
+                    # requests' chains.
+                    self.draft.pools, self.draft.key_data = \
+                        self._draft_prefill(
+                            self.draft.params, self.draft.pools,
+                            jnp.asarray(d_bt), tok_dev, starts_dev,
+                            fslots_dev, self.draft.key_data,
+                            jnp.asarray(d_keys),
+                        )
+            self._tick_unsynced = True
+            if fin:
+                t1 = time.perf_counter()
+                with span("serving.prefill_tick.first_sync"):
+                    toks = np.asarray(jax.device_get(first))
+                t_sync = time.perf_counter() - t1
+                self._tick_unsynced = False
+                with span("serving.prefill_tick.emit"):
+                    done_tasks = {id(t) for _, t in fin}
+                    for r, task in fin:
+                        self._finish_or_activate(task.req, task.slot,
+                                                 int(toks[r]), finished)
+                    self._prefilling = [t for t in self._prefilling
+                                        if id(t) not in done_tasks]
+        if self.obs.enabled:
+            self.obs.on_prefill_tick(len(tasks),
+                                     int(nvalid[:len(tasks)].sum()),
+                                     r_rows * c,
+                                     time.perf_counter() - t0 - t_sync)
         return finished
 
     # ---- on-demand growth + preemption (serving/scheduler decisions)
@@ -1037,33 +1072,34 @@ class ServingEngine:
             return
         look = (self.spec.k + 1) if self.spec is not None else 1
         bs = self.kv.block_size
-        for slot in np.flatnonzero(self.active).tolist():
-            if not self.active[slot]:
-                continue  # retired/preempted by an earlier row's growth
-            target = min(int(self._dev_len[slot]) + look, self.max_len)
-            covered = len(self.kv.alloc.owned_by(slot)) * bs
-            if target <= covered:
-                ok = True
-            else:
-                # A grow is due: opportunistically take one block of
-                # slack so the table (re-uploaded whenever it dirties)
-                # dirties half as often — but only when the slack fits
-                # without stalling or evicting anyone; under pressure
-                # fall back to the exact target.
-                slacked = min(target + bs, self.max_len)
-                ok = slacked > target and self._extend_both(slot, slacked)
-                if not ok:
-                    ok = self._grow_row(slot, target)
-            if not self.active[slot]:
-                continue  # the row itself was evicted to make room
-            if ok:
-                if self._stalled[slot]:
-                    self._stalled[slot] = False
+        with self.obs.span("serving.grow"):
+            for slot in np.flatnonzero(self.active).tolist():
+                if not self.active[slot]:
+                    continue  # retired/preempted by an earlier row's growth
+                target = min(int(self._dev_len[slot]) + look, self.max_len)
+                covered = len(self.kv.alloc.owned_by(slot)) * bs
+                if target <= covered:
+                    ok = True
+                else:
+                    # A grow is due: opportunistically take one block of
+                    # slack so the table (re-uploaded whenever it dirties)
+                    # dirties half as often — but only when the slack fits
+                    # without stalling or evicting anyone; under pressure
+                    # fall back to the exact target.
+                    slacked = min(target + bs, self.max_len)
+                    ok = slacked > target and self._extend_both(slot, slacked)
+                    if not ok:
+                        ok = self._grow_row(slot, target)
+                if not self.active[slot]:
+                    continue  # the row itself was evicted to make room
+                if ok:
+                    if self._stalled[slot]:
+                        self._stalled[slot] = False
+                        self._host_dirty = True
+                elif not self._stalled[slot]:
+                    self._stalled[slot] = True
                     self._host_dirty = True
-            elif not self._stalled[slot]:
-                self._stalled[slot] = True
-                self._host_dirty = True
-                self.sched_events["stalls"] += 1
+                    self.sched_events["stalls"] += 1
 
     def _grow_row(self, slot: int, target: int) -> bool:
         """True once slot's reservation covers ``target`` tokens (or the
@@ -1390,10 +1426,13 @@ class ServingEngine:
         return self._pop_finished()
 
     def _drain_ring(self) -> None:
-        if self.obs.enabled and self._ring:
+        if not self._ring:
+            return
+        if self.obs.enabled:
             self.obs.on_drain(len(self._ring))
-        while self._ring:
-            self._consume_one()
+        with self.obs.span("serving.drain"):
+            while self._ring:
+                self._consume_one()
 
     def _pop_finished(self) -> List[Request]:
         out, self._pending_finished = self._pending_finished, []
@@ -1734,7 +1773,9 @@ class ServingEngine:
             self._dev_len += mask  # each dispatched row writes one entry
         self._note_occupancy(mask)
         self._ring.append(_InFlight(sampled, mask,
-                                    time.perf_counter() - t0))
+                                    time.perf_counter() - t0,
+                                    tick_ahead=self._tick_unsynced))
+        self._tick_unsynced = False
         if self.obs.enabled:
             self._obs_dispatch("decode", mask)
 
@@ -1789,7 +1830,9 @@ class ServingEngine:
             self._dev_len += (self.spec.k + 1) * mask
         self._note_occupancy(mask)
         self._ring.append(_InFlight(pack, mask, time.perf_counter() - t0,
-                                    spec=True, k_row=self._k_row.copy()))
+                                    spec=True, k_row=self._k_row.copy(),
+                                    tick_ahead=self._tick_unsynced))
+        self._tick_unsynced = False
         if self.obs.enabled:
             self._obs_dispatch("spec", mask)
 
@@ -1854,18 +1897,21 @@ class ServingEngine:
                 f"{timeout}s (dispatch {entry.dispatch_s:.3f}s + sync "
                 f"{t_sync:.3f}s)", kind="step_timeout",
                 step=self._step_idx, snapshot=self.engine_snapshot())
-        if entry.spec:
-            finished = self._commit_spec(entry, toks)
-        else:
-            finished = self._commit_decode(entry, toks)
+        with self.obs.span("serving.commit"):
+            if entry.spec:
+                finished = self._commit_spec(entry, toks)
+            else:
+                finished = self._commit_decode(entry, toks)
         self._pending_finished.extend(finished)
         t_host = time.perf_counter() - t0 - t_sync
+        step_s = entry.dispatch_s + t_sync + t_host
         self.step_device_wait_s.append(t_sync)
         self.step_host_s.append(t_host)
-        self.step_times.append(entry.dispatch_s + t_sync + t_host)
+        self.step_times.append(step_s)
         if self.obs.enabled:
             self.obs.on_step_consume("spec" if entry.spec else "decode",
-                                     t_sync, t_host)
+                                     t_sync, t_host, step_s,
+                                     entry.tick_ahead)
 
     def _commit_decode(self, entry: _InFlight,
                        toks: np.ndarray) -> List[Request]:

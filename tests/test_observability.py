@@ -91,6 +91,19 @@ def _request_event_multiset(tel):
 # --------------------------------------------------- bit-identity pins
 
 
+def _bench_observer(traced):
+    """The benchmark harness's engine observer (bench/harness/loadgen.py):
+    a subclass of the no-op telemetry that records host timestamps."""
+    import importlib
+    import sys
+    from pathlib import Path
+
+    bench = str(Path(__file__).resolve().parents[1] / "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    return importlib.import_module("harness.loadgen").make_observer(traced)
+
+
 class TestBitIdentity:
     @pytest.mark.parametrize("paged", [True, False])
     def test_greedy_streams_identical_with_telemetry(self, tiny_lm, prompts,
@@ -102,6 +115,11 @@ class TestBitIdentity:
                               telemetry=Telemetry(), paged=paged)
             assert got == base, f"depth={depth} paged={paged}"
             assert eng.obs.enabled
+        for traced in (False, True):
+            got, eng = _serve(model, params, 2, prompts, LENS,
+                              telemetry=_bench_observer(traced), paged=paged)
+            assert got == base, f"observer traced={traced} paged={paged}"
+            assert sum(eng.obs.count.values()) == sum(LENS)
 
     def test_spec_streams_identical_with_telemetry(self, tiny_lm,
                                                    draft_params, prompts):
@@ -186,6 +204,8 @@ class TestDisabledPath:
         NULL_TELEMETRY.on_submit(0, 1, 2)
         NULL_TELEMETRY.on_step_dispatch("decode", 1, 2, 0.1)
         NULL_TELEMETRY.on_spec_row(4, 2)
+        NULL_TELEMETRY.on_step_consume("decode", 1e-3, 1e-4, 2e-3, True)
+        NULL_TELEMETRY.on_prefill_tick(2, 30, 128, 1e-3)
         assert NULL_TELEMETRY.snapshot() == {}
         assert not hasattr(NULL_TELEMETRY, "__dict__")  # __slots__ pin
 
