@@ -336,7 +336,7 @@ def make_paged_prefill_chunk_step(model: Model) -> Callable:
     to R requests at once.  Each row r writes ``tokens[r]`` at logical
     positions ``starts[r]..starts[r]+C-1`` of its block-table row and
     attends causally over its own prefix — so a very long prompt is admitted
-    as a sequence of fixed-shape chunk calls interleaved with decode steps
+    as a sequence of fixed-width chunk calls interleaved with decode steps
     instead of one monolithic prefill that stalls the running batch.
 
     Only ``nvalid[r]`` leading tokens of a row's chunk are real; garbage
@@ -351,7 +351,8 @@ def make_paged_prefill_chunk_step(model: Model) -> Callable:
     make sampled streams independent of slot assignment and admission
     timing, which the depth-K pipeline shifts) and sample their first
     token from the last real position's logits.
-    Compiles exactly once — the (R, C) shape never changes."""
+    Any row count R works (rows past the prompts are padding); the engine
+    compiles one per rung of ``serving.engine.prefill_tick_rungs``."""
 
     def paged_prefill_chunk_step(params, pools, bt_rows, tokens, starts,
                                  nvalid, fslots, budgets, row_keys,

@@ -136,7 +136,8 @@ class Telemetry:
         self.steps_dispatched = m.counter(
             "serving_steps_dispatched_total", "decode/spec root dispatches")
         self.prefill_ticks = m.counter(
-            "serving_prefill_ticks_total", "chunked-prefill ticks")
+            "serving_prefill_ticks_total", "chunked-prefill ticks by the "
+            "row count they ran at (the rung)", labelnames=("rows",))
         self.prefill_tick_fill = m.histogram(
             "serving_prefill_tick_fill_frac", "prompt tokens / token slots "
             "per chunked-prefill tick", buckets=FRACTION_BUCKETS,
@@ -308,16 +309,17 @@ class Telemetry:
             self.profile.tick_consume()
 
     def on_prefill_tick(self, rows: int, tokens: int, slots: int,
-                        host_s: float) -> None:
+                        host_s: float, rung: int) -> None:
         """One chunked-prefill tick: the prompts it carried, their
-        tokens, the token slots it computed (rows x chunk, padding
-        included) and its host time (wall time less the first-token
-        sync)."""
-        self.prefill_ticks.inc()
+        tokens, the token slots it computed (rung x chunk, padding
+        included), its host time (wall time less the first-token sync)
+        and the row count it ran at.  The all-padding ticks that compile
+        each rung before the first are not reported."""
+        self.prefill_ticks.labels(rows=str(rung)).inc()
         self.prefill_tick_fill.observe(tokens / slots)
         self.tracer.complete("tick", "step", host_s, PID_ENGINE, 0,
                              {"rows": rows, "tokens": tokens,
-                              "slots": slots})
+                              "slots": slots, "rung": rung})
 
     def on_drain(self, n_in_flight: int) -> None:
         self.drains.inc()
@@ -508,7 +510,7 @@ class _NullTelemetry:
     def on_step_consume(self, kind, sync_s, host_s, step_s, tick_ahead):
         pass
 
-    def on_prefill_tick(self, rows, tokens, slots, host_s):
+    def on_prefill_tick(self, rows, tokens, slots, host_s, rung):
         pass
 
     def on_drain(self, n_in_flight):

@@ -53,9 +53,11 @@ stacks — see serving/kvcache/):
     (prompt + max_new reserved up front; exhaustion surfaces only as
     admission backpressure, never mid-decode).
   * Prefill is CHUNKED: prompts stream into their blocks ``prefill_chunk``
-    tokens per engine iteration through one fixed-shape jit root (compiles
-    exactly once), interleaved with decode steps so a very long prompt
-    cannot stall the running batch.
+    tokens per engine iteration through one jit root, interleaved with
+    decode steps so a very long prompt cannot stall the running batch.  A
+    tick runs at the smallest of a few row counts (``prefill_tick_rungs``)
+    that holds the prompts in flight, and every row count is compiled and
+    run before the first real tick.
   * Decode attends through ``kernels/paged_attention`` (Pallas kernel
     streaming exactly the live pages on TPU, jnp gather oracle elsewhere),
     honoring the int8 KV quantization of the dense path.
@@ -250,6 +252,37 @@ class _InFlight:
     # A chunk tick was dispatched after the previous ring entry and its
     # output was never synced: this step's sync waits for that tick too.
     tick_ahead: bool = False
+
+
+@dataclasses.dataclass
+class _TickInputs:
+    """Host inputs of one chunked-prefill tick, one row per prompt
+    (``make_paged_prefill_chunk_step`` documents each); ``d_keys`` and
+    ``d_bt`` feed the draft twin when speculating."""
+    tokens: np.ndarray
+    starts: np.ndarray
+    nvalid: np.ndarray
+    fslots: np.ndarray
+    budgets: np.ndarray
+    rkeys: np.ndarray
+    temps: np.ndarray
+    bt_rows: np.ndarray
+    d_keys: Optional[np.ndarray]
+    d_bt: Optional[np.ndarray]
+
+
+def prefill_tick_rungs(max_batch: int, dp_shards: int = 1) -> Tuple[int, ...]:
+    """Row counts a chunked-prefill tick runs at, ascending: ``max_batch``
+    and each quarter of it that is still at least ``dp_shards``, rounded up
+    to a multiple of ``dp_shards`` (the chunk root's per-row inputs shard
+    over DP).  A tick takes the smallest that holds its prompts, so it
+    computes few padding rows at a handful of compiled shapes."""
+    rungs = [max_batch]
+    r = max_batch // 4
+    while r >= max(dp_shards, 1):
+        rungs.append(-(-r // dp_shards) * dp_shards)
+        r //= 4
+    return tuple(reversed(rungs))
 
 
 _PIPELINE_DEPTH_ENV = "REPRO_SERVING_PIPELINE_DEPTH"
@@ -464,6 +497,8 @@ class ServingEngine:
                 dp_shards=self.dp_shards, par=par,
             )
             self.prefill_chunk = prefill_chunk
+            self._tick_rungs = prefill_tick_rungs(max_batch, self.dp_shards)
+            self._ticks_warm = False
             self._sh = (ServingShardings(par, params, self.kv.shardings,
                                          max_batch)
                         if par is not None else None)
@@ -953,54 +988,47 @@ class ServingEngine:
     def _prefill_tick(self) -> List[Request]:
         """Advance every in-flight prefill by ONE chunk (single jit call).
         run() interleaves these ticks with decode steps, so long prompts
-        stream in without stalling live rows."""
-        t0 = time.perf_counter()
-        t_sync = 0.0
+        stream in without stalling live rows.  The tick runs at the
+        smallest rung of ``_tick_rungs`` that holds its prompts; the first
+        tick compiles and runs every rung first."""
         span = self.obs.span
         c = self.prefill_chunk
-        r_rows = self.max_batch
-        tasks = self._prefilling[:r_rows]
+        tasks = self._prefilling[:self.max_batch]
+        rows = next(r for r in self._tick_rungs if r >= len(tasks))
         finished: List[Request] = []
+        t_sync = 0.0
         with span("serving.prefill_tick"):
+            if not self._ticks_warm:
+                with span("serving.prefill_tick.warm"):
+                    for r in self._tick_rungs:
+                        self._dispatch_tick(self._padding_tick(r))
+                self._ticks_warm = True
+            t0 = time.perf_counter()
             with span("serving.prefill_tick.build"):
-                tokens = np.zeros((r_rows, c), np.int32)
-                starts = np.zeros((r_rows,), np.int32)
-                nvalid = np.ones((r_rows,), np.int32)
-                # Padding rows name slot max_batch: the root drops them.
-                fslots = np.full((r_rows,), self.max_batch, np.int32)
-                budgets = np.zeros((r_rows,), np.int32)
-                rkeys = np.zeros((r_rows, 2), np.uint32)
-                d_keys = (np.zeros((r_rows, 2), np.uint32)
-                          if self.spec is not None else None)
-                temps = np.zeros((r_rows,), np.float32)
-                bt_rows = np.full((r_rows, self.kv.max_blocks_per_row), -1,
-                                  np.int32)
-                d_bt = (np.full((r_rows, self.kv.max_blocks_per_row), -1,
-                                np.int32)
-                        if self.spec is not None else None)
+                a = self._padding_tick(rows)
                 fin: List[tuple] = []
                 for r, task in enumerate(tasks):
                     p = task.req.prompt
                     n = min(len(p) - task.pos, c)
                     if self.obs.enabled and task.pos == 0:
                         self.obs.on_first_chunk(task.req.uid, task.slot)
-                    tokens[r, :n] = p[task.pos: task.pos + n]
-                    starts[r] = task.pos
-                    nvalid[r] = n
-                    temps[r] = task.req.temperature
-                    bt_rows[r] = self.kv.table_np[task.slot]
-                    if d_bt is not None:
-                        d_bt[r] = self.draft.kv.table_np[task.slot]
+                    a.tokens[r, :n] = p[task.pos: task.pos + n]
+                    a.starts[r] = task.pos
+                    a.nvalid[r] = n
+                    a.temps[r] = task.req.temperature
+                    a.bt_rows[r] = self.kv.table_np[task.slot]
+                    if a.d_bt is not None:
+                        a.d_bt[r] = self.draft.kv.table_np[task.slot]
                     task.pos += n
                     if task.pos >= len(p):
-                        fslots[r] = task.slot
+                        a.fslots[r] = task.slot
                         # Budget after the first sampled token: fresh
                         # requests have generated == []; a reprefill-resumed
                         # request's prompt already contains its generated
                         # tokens, so its budget is what remains AFTER
                         # re-sampling the next one.
-                        budgets[r] = max(0, task.req.max_new_tokens
-                                         - len(task.req.generated) - 1)
+                        a.budgets[r] = max(0, task.req.max_new_tokens
+                                           - len(task.req.generated) - 1)
                         fin.append((r, task))
                 if fin:
                     # Per-request sampling chains for the finishing rows
@@ -1009,33 +1037,12 @@ class ServingEngine:
                     uids = [t.req.uid for _, t in fin]
                     fr = [r for r, _ in fin]
                     with span("serving.request_keys"):
-                        rkeys[fr] = self._request_keys(uids)
-                        if d_keys is not None:
-                            d_keys[fr] = self._request_keys(uids, draft=True)
+                        a.rkeys[fr] = self._request_keys(uids)
+                        if a.d_keys is not None:
+                            a.d_keys[fr] = self._request_keys(uids,
+                                                              draft=True)
             with span("serving.prefill_tick.dispatch"):
-                tok_dev, starts_dev = jnp.asarray(tokens), jnp.asarray(starts)
-                fslots_dev = jnp.asarray(fslots)
-                (first, self.kv.pools, self.cache_len, self.last_token,
-                 self.budget_dev, self.key_data,
-                 self._active_dev) = self._chunk_step(
-                    self.params, self.kv.pools, jnp.asarray(bt_rows),
-                    tok_dev, starts_dev, jnp.asarray(nvalid),
-                    fslots_dev, jnp.asarray(budgets), jnp.asarray(rkeys),
-                    self.cache_len, self.last_token, self.budget_dev,
-                    self.key_data, jnp.asarray(temps), self._active_dev,
-                )
-                if self.spec is not None:
-                    # Stream the same chunk into the draft pools (its own
-                    # block tables; lengths/last tokens are shared with the
-                    # target) and reset finishing rows' draft keys to their
-                    # requests' chains.
-                    self.draft.pools, self.draft.key_data = \
-                        self._draft_prefill(
-                            self.draft.params, self.draft.pools,
-                            jnp.asarray(d_bt), tok_dev, starts_dev,
-                            fslots_dev, self.draft.key_data,
-                            jnp.asarray(d_keys),
-                        )
+                first = self._dispatch_tick(a)
             self._tick_unsynced = True
             if fin:
                 t1 = time.perf_counter()
@@ -1052,10 +1059,55 @@ class ServingEngine:
                                         if id(t) not in done_tasks]
         if self.obs.enabled:
             self.obs.on_prefill_tick(len(tasks),
-                                     int(nvalid[:len(tasks)].sum()),
-                                     r_rows * c,
-                                     time.perf_counter() - t0 - t_sync)
+                                     int(a.nvalid[:len(tasks)].sum()),
+                                     rows * c,
+                                     time.perf_counter() - t0 - t_sync,
+                                     rows)
         return finished
+
+    def _padding_tick(self, rows: int) -> _TickInputs:
+        """Host inputs of a tick of ``rows`` rows that are all padding:
+        each names slot ``max_batch`` and holds only -1 table entries, so
+        the root drops every write it makes."""
+        c, m = self.prefill_chunk, self.kv.max_blocks_per_row
+        spec = self.spec is not None
+        return _TickInputs(
+            tokens=np.zeros((rows, c), np.int32),
+            starts=np.zeros((rows,), np.int32),
+            nvalid=np.ones((rows,), np.int32),
+            fslots=np.full((rows,), self.max_batch, np.int32),
+            budgets=np.zeros((rows,), np.int32),
+            rkeys=np.zeros((rows, 2), np.uint32),
+            temps=np.zeros((rows,), np.float32),
+            bt_rows=np.full((rows, m), -1, np.int32),
+            d_keys=np.zeros((rows, 2), np.uint32) if spec else None,
+            d_bt=np.full((rows, m), -1, np.int32) if spec else None,
+        )
+
+    def _dispatch_tick(self, a: _TickInputs) -> jax.Array:
+        """Run the chunk root on one tick's inputs, reassigning the state
+        it donates; returns the device vector of sampled first tokens."""
+        tok_dev, starts_dev = jnp.asarray(a.tokens), jnp.asarray(a.starts)
+        fslots_dev = jnp.asarray(a.fslots)
+        (first, self.kv.pools, self.cache_len, self.last_token,
+         self.budget_dev, self.key_data,
+         self._active_dev) = self._chunk_step(
+            self.params, self.kv.pools, jnp.asarray(a.bt_rows),
+            tok_dev, starts_dev, jnp.asarray(a.nvalid),
+            fslots_dev, jnp.asarray(a.budgets), jnp.asarray(a.rkeys),
+            self.cache_len, self.last_token, self.budget_dev,
+            self.key_data, jnp.asarray(a.temps), self._active_dev,
+        )
+        if self.spec is not None:
+            # Stream the same chunk into the draft pools (its own block
+            # tables; lengths/last tokens are shared with the target) and
+            # reset finishing rows' draft keys to their requests' chains.
+            self.draft.pools, self.draft.key_data = self._draft_prefill(
+                self.draft.params, self.draft.pools, jnp.asarray(a.d_bt),
+                tok_dev, starts_dev, fslots_dev, self.draft.key_data,
+                jnp.asarray(a.d_keys),
+            )
+        return first
 
     # ---- on-demand growth + preemption (serving/scheduler decisions)
 

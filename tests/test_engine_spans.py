@@ -31,6 +31,7 @@ PARENTS = {
     "serving.ring_sync": {None, "serving.drain"},
     "serving.commit": {None, "serving.drain"},
     "serving.prefill_tick": {"serving.admit"},
+    "serving.prefill_tick.warm": {"serving.prefill_tick"},
     "serving.prefill_tick.build": {"serving.prefill_tick"},
     "serving.request_keys": {"serving.prefill_tick.build"},
     "serving.prefill_tick.dispatch": {"serving.prefill_tick"},
@@ -49,7 +50,7 @@ class Recorder(Telemetry):
         super().__init__()
         self.stack = []
         self.spans = []      # (name, enclosing span or None)
-        self.ticks = []      # (rows, tokens, slots, host_s)
+        self.ticks = []      # (rows, tokens, slots, host_s, rung)
         self.consumes = []   # (kind, sync_s, host_s, step_s, tick_ahead)
 
     @contextlib.contextmanager
@@ -60,9 +61,9 @@ class Recorder(Telemetry):
             yield
         assert self.stack.pop() == name
 
-    def on_prefill_tick(self, rows, tokens, slots, host_s):
-        super().on_prefill_tick(rows, tokens, slots, host_s)
-        self.ticks.append((rows, tokens, slots, host_s))
+    def on_prefill_tick(self, rows, tokens, slots, host_s, rung):
+        super().on_prefill_tick(rows, tokens, slots, host_s, rung)
+        self.ticks.append((rows, tokens, slots, host_s, rung))
 
     def on_step_consume(self, kind, sync_s, host_s, step_s, tick_ahead):
         super().on_step_consume(kind, sync_s, host_s, step_s, tick_ahead)
@@ -124,15 +125,44 @@ def test_spans_are_chrome_events_on_the_span_lane(served):
 
 def test_prefill_ticks_count_prompt_tokens_and_slots(served):
     _, rec = served
-    assert sum(t for _, t, _, _ in rec.ticks) == sum(PROMPT_LENS)
-    assert all(s == MAX_BATCH * CHUNK for _, _, s, _ in rec.ticks)
-    assert all(1 <= r <= MAX_BATCH and h >= 0 for r, _, _, h in rec.ticks)
+    assert sum(t for _, t, _, _, _ in rec.ticks) == sum(PROMPT_LENS)
+    # MAX_BATCH 2 has the one rung 2.
+    assert all(s == MAX_BATCH * CHUNK for _, _, s, _, _ in rec.ticks)
+    assert all(1 <= r <= MAX_BATCH and h >= 0
+               for r, _, _, h, _ in rec.ticks)
     # Both prompts stream together: three ticks, the last with one row.
-    assert [r for r, _, _, _ in rec.ticks] == [2, 2, 1]
+    assert [r for r, _, _, _, _ in rec.ticks] == [2, 2, 1]
     snap = rec.metrics.snapshot()
     assert snap["serving_prefill_ticks_total"]["series"][0]["value"] == 3
     fill = snap["serving_prefill_tick_fill_frac"]["series"][0]
     assert fill["count"] == 3
+
+
+def test_prefill_ticks_count_by_rung(tiny_lm):
+    """A tick computes rung x chunk token slots, at the smallest rung that
+    holds its prompts, and counts under that rung's ``rows`` label.  The
+    all-padding ticks that compile every rung before the first real one
+    are left out of the count: only their one span shows them."""
+    model, params = tiny_lm
+    rec = Recorder()
+    eng = ServingEngine(model, params, max_batch=8, max_len=64, seed=0,
+                        paged=True, block_size=8, prefill_chunk=CHUNK,
+                        telemetry=rec)
+    assert eng._tick_rungs == (2, 8)
+    rng = np.random.default_rng(5)
+    eng.submit(_prompt(rng, 12), max_new_tokens=2)  # two 1-row ticks
+    eng.run()
+    for n in (20, 13, 9):  # rows 3, 3, 1
+        eng.submit(_prompt(rng, n), max_new_tokens=2)
+    eng.run()
+    assert [(r, g) for r, _, _, _, g in rec.ticks] == [
+        (1, 2), (1, 2), (3, 8), (3, 8), (1, 2)]
+    assert all(s == g * CHUNK for _, _, s, _, g in rec.ticks)
+    series = rec.metrics.snapshot()["serving_prefill_ticks_total"]["series"]
+    assert {s["labels"]["rows"]: s["value"] for s in series} == {
+        "2": 3, "8": 2}
+    names = [name for name, _ in rec.spans]
+    assert names.count("serving.prefill_tick.warm") == 1
 
 
 def test_step_consume_matches_step_times(served):

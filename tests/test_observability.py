@@ -205,7 +205,7 @@ class TestDisabledPath:
         NULL_TELEMETRY.on_step_dispatch("decode", 1, 2, 0.1)
         NULL_TELEMETRY.on_spec_row(4, 2)
         NULL_TELEMETRY.on_step_consume("decode", 1e-3, 1e-4, 2e-3, True)
-        NULL_TELEMETRY.on_prefill_tick(2, 30, 128, 1e-3)
+        NULL_TELEMETRY.on_prefill_tick(2, 30, 128, 1e-3, 2)
         assert NULL_TELEMETRY.snapshot() == {}
         assert not hasattr(NULL_TELEMETRY, "__dict__")  # __slots__ pin
 

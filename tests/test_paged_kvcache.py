@@ -14,8 +14,9 @@ import pytest
 
 from repro.configs.paper_models import small_lm
 from repro.models import build_model, cache_layout
-from repro.serving.engine import ServingEngine
+from repro.serving.engine import ServingEngine, prefill_tick_rungs
 from repro.serving.kvcache import BlockAllocator, PagedKVCache
+from repro.serving.spec import SpecConfig
 
 VOCAB = 256
 
@@ -346,17 +347,90 @@ class TestPagedDenseEquivalence:
         assert dense == paged, why
 
     def test_chunked_prefill_compiles_once(self, tiny_lm):
-        """The fixed-shape chunk step compiles exactly once regardless of
-        prompt-length mix (the dense path compiles once per bucket)."""
+        """The chunk step compiles once per rung of the tick's row ladder,
+        every rung at the first tick, and never again over a mix of prompt
+        lengths and arrival counts (the dense path compiles once per
+        bucket)."""
         model, params = tiny_lm
         rng = np.random.default_rng(7)
-        eng = ServingEngine(model, params, max_batch=2, max_len=128,
+        eng = ServingEngine(model, params, max_batch=8, max_len=128,
                             paged=True, prefill_chunk=16)
-        for n in (3, 17, 40, 100):
-            eng.submit(rng.integers(2, 200, size=n), max_new_tokens=2)
-        out = eng.run()
-        assert len(out) == 4
-        assert eng._chunk_step._cache_size() == 1
+        assert eng._tick_rungs == (2, 8)
+        eng.submit(rng.integers(2, 200, size=3), max_new_tokens=2)
+        eng.run(max_steps=1)
+        assert eng._chunk_step._cache_size() == len(eng._tick_rungs)
+        n = 1
+        for lens in ((17,), (40, 100, 5), (3, 17, 40, 100, 9, 33, 60, 2),
+                     (1, 64)):
+            for plen in lens:
+                eng.submit(rng.integers(2, 200, size=plen), max_new_tokens=2)
+            n += len(lens)
+            eng.run()
+            assert eng._chunk_step._cache_size() == len(eng._tick_rungs)
+        assert len(eng.finished_requests) == n
+
+    @pytest.mark.parametrize("max_batch,dp_shards,rungs", [
+        (32, 1, (2, 8, 32)),
+        (2, 1, (2,)),
+        (16, 1, (1, 4, 16)),
+        (8, 2, (2, 8)),
+    ])
+    def test_prefill_tick_rungs(self, max_batch, dp_shards, rungs):
+        """The tick's row ladder: max_batch and each quarter of it still
+        at least dp_shards, rounded up to a multiple of dp_shards."""
+        assert prefill_tick_rungs(max_batch, dp_shards) == rungs
+
+    @pytest.mark.parametrize("variant", ["plain", "spec", "int8"])
+    def test_tick_rungs_match_dense(self, tiny_lm, variant):
+        """Ticks of 1, 2, 3 and 8 prefilling rows (rungs 2, 2, 8, 8 of
+        max_batch 8) give the greedy tokens of an engine whose every tick
+        runs all 8 rows, and of the dense-slab engine: the rung adds or
+        drops only padding rows.  ``spec`` streams every rung into the
+        draft pools too.  ``int8`` is held to the 8-row ticks alone: its
+        chunked prefill attends over earlier chunks' quantized keys, which
+        dense prefill never reads, so one prompt here leaves the dense
+        tokens whatever the tick's rows."""
+        model, params = tiny_lm
+        rng = np.random.default_rng(30)
+        prompts = [rng.integers(2, 200, size=n)
+                   for n in (40, 45, 41, 48, 52, 43, 60, 47)]
+        kw = {"kv_quant": variant == "int8"}
+        dense = ServingEngine(model, params, max_batch=8, max_len=96,
+                              paged=False, **kw)
+        d_uids = [dense.submit(p, max_new_tokens=6) for p in prompts]
+        want = dense.run()
+        if variant == "spec":
+            kw["spec_config"] = SpecConfig(draft_params=jax.tree.map(
+                lambda x: x * 1.02 if x.ndim >= 2 else x, params), k=3)
+
+        def serve(rungs=None):
+            eng = ServingEngine(model, params, max_batch=8, max_len=96,
+                                paged=True, block_size=16, prefill_chunk=8,
+                                **kw)
+            if rungs is not None:
+                eng._tick_rungs = rungs
+            rows = []
+            tick = eng._prefill_tick
+
+            def counting_tick():
+                rows.append(len(eng._prefilling))
+                return tick()
+
+            eng._prefill_tick = counting_tick
+            uids = []
+            for group in (prompts[:1], prompts[1:2], prompts[2:3],
+                          prompts[3:]):
+                uids += [eng.submit(p, max_new_tokens=6) for p in group]
+                eng.run(max_steps=1)
+            eng.run()
+            assert rows[:4] == [1, 2, 3, 8]
+            assert eng._chunk_step._cache_size() == len(eng._tick_rungs)
+            return [eng.finished_requests[u].generated for u in uids]
+
+        got = serve()
+        assert got == serve(rungs=(8,))
+        if variant != "int8":
+            assert got == [want[u] for u in d_uids]
 
 
 # ------------------------------------------------- pool pressure + reuse

@@ -92,8 +92,9 @@ class TestBatchedSampling:
 
 class TestPrefillBuckets:
     """Bucketed prefill is the DENSE-slab admission path (attention models
-    default to the paged engine, whose fixed-shape chunked prefill compiles
-    exactly once — see test_paged_kvcache.py); pin paged=False here."""
+    default to the paged engine, whose chunked prefill compiles once per
+    rung of its row ladder — see test_paged_kvcache.py); pin paged=False
+    here."""
 
     def test_compilations_bounded_by_buckets_not_lengths(self, tiny_lm):
         """Prompts of lengths {7, 9, 250} span two power-of-two buckets

@@ -198,6 +198,32 @@ class TestSharded22Equivalence:
             assert eng.cache_stats()["mesh"] == {"dp": 2, "tp": 2,
                                                  "devices": 4}
 
+    def test_tick_rungs_identical(self, tiny_lm, par22):
+        """max_batch 8 over DP 2 ticks at rungs 2 and 8 (multiples of the
+        DP shards): the sharded chunk root at either rung gives the
+        meshless tokens."""
+        model, params = tiny_lm
+        rng = np.random.default_rng(9)
+        groups = [[rng.integers(2, 200, size=n)] for n in (20, 17)]
+        groups.append([rng.integers(2, 200, size=n)
+                       for n in (18, 25, 9, 30)])
+
+        def serve(par):
+            eng = ServingEngine(model, params, max_batch=8, max_len=64,
+                                paged=True, prefill_chunk=8, parallelism=par)
+            uids = []
+            for group in groups:
+                uids += [eng.submit(p, max_new_tokens=5) for p in group]
+                eng.run(max_steps=1)
+            eng.run()
+            return eng, [eng.finished_requests[u].generated for u in uids]
+
+        _, base = serve(None)
+        eng, shard = serve(par22)
+        assert eng._tick_rungs == (2, 8)
+        assert eng._chunk_step._cache_size() == 2
+        assert shard == base
+
     def test_temperature_sampling_identical_both_layouts(self, tiny_lm,
                                                          prompts, par22):
         """Per-slot PRNG keys are slot state, so sharding must not change

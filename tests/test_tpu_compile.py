@@ -152,3 +152,26 @@ class TestNestedLowrank:
                 _sds((k2, n_pad), bf16, one_chip))
         compiled = jax.jit(nested_lowrank_matmul).lower(*args).compile()
         assert "tpu_custom_call" in compiled.as_text()
+
+    @pytest.mark.parametrize("rows", [128, 512])
+    def test_compiles_for_v5e_at_chunk_tick_rows(self, one_chip, rows):
+        """A chunked-prefill tick at rungs 2 and 8 of 32 rows, 64-token
+        chunks, flattens to 128 and 512 rows: k/v's factors at ratio 0.2
+        pass the VMEM gate there, so the tick runs them in the kernel."""
+        from repro.kernels.nested_lowrank.nested_lowrank import (
+            VMEM_LIMIT_BYTES,
+            kernel_vmem_bytes,
+            nested_lowrank_matmul,
+        )
+
+        k_in, n, k1, k2 = _planned("attn/wk", 0.2)
+        assert kernel_vmem_bytes(rows, k_in, 256, k1, k2, block_n=256,
+                                 dtype="bfloat16") <= VMEM_LIMIT_BYTES
+        bf16 = jnp.bfloat16
+        args = (_sds((rows, k_in), bf16, one_chip),
+                _sds((k_in, k1), bf16, one_chip),
+                _sds((k1, n), bf16, one_chip),
+                _sds((k_in, k2), bf16, one_chip),
+                _sds((k2, n), bf16, one_chip))
+        compiled = jax.jit(nested_lowrank_matmul).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
