@@ -16,6 +16,7 @@ import time
 from typing import Callable, Optional
 
 import numpy as np
+from repro.models import build_model
 
 from . import check, loadgen, spec, traffic, weights
 
@@ -54,14 +55,52 @@ def engine_seed(seed: int) -> int:
                >> 1)
 
 
-def build_engine(model, params, config: dict, seed: int, observer):
+def parallelism(cell: spec.Cell, devices):
+    """The program's Parallelism over the cell's (dp, tp) serving mesh of
+    ``devices``; None on one chip, where the engine runs meshless."""
+    dp, tp = cell.mesh()
+    if dp * tp == 1:
+        return None
+    from repro.launch.mesh import make_serving_mesh
+    from repro.parallel.sharding import make_parallelism
+
+    mesh = make_serving_mesh(dp, tp)
+    if set(mesh.devices.flat) != set(devices):
+        raise ValueError(f"the (dp {dp}, tp {tp}) mesh is not the cell's "
+                         f"{len(devices)} devices")
+    return make_parallelism(mesh)
+
+
+def build_model_and_params(cell: spec.Cell, seed: int, devices):
+    """(model, params, par): the model the cell's configuration describes,
+    its factored params drawn from ``seed``, and the Parallelism of its
+    mesh (None on one chip).  On a mesh each param is drawn straight into
+    the sharding the engine gives it (``ServingShardings.params``)."""
+    par = parallelism(cell, devices)
+    config = cell.config
+    model = build_model(spec.model_config(config))
+    shardings = None
+    if par is not None:
+        from repro.launch.steps import ServingShardings
+
+        shapes = weights.param_shapes(model, config["compression"])
+        shardings = ServingShardings(par, shapes, None,
+                                     config["deployment"]["max_batch"]).params
+    params = weights.build_params(model, config["compression"], seed,
+                                  shardings)
+    return model, params, par
+
+
+def build_engine(model, params, config: dict, seed: int, observer,
+                 par=None):
     from repro.serving.engine import ServingEngine
 
     dep = config["deployment"]
     return ServingEngine(model, params, max_batch=dep["max_batch"],
                          max_len=dep["max_len"], seed=engine_seed(seed),
                          paged=True, block_size=dep["block_size"],
-                         num_blocks=dep["num_blocks"], telemetry=observer)
+                         num_blocks=dep["num_blocks"], parallelism=par,
+                         telemetry=observer)
 
 
 def warm_up(eng, vocab: int) -> None:
@@ -93,7 +132,6 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     import jax
 
     from repro.launch.compile_cache import enable_compilation_cache
-    from repro.models import build_model
 
     enable_compilation_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
@@ -102,12 +140,11 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     dev = devices[0]
     config = cell.config
     marks = [("start", t_process), ("imports", time.perf_counter())]
-    model = build_model(spec.model_config(config))
-    params = weights.build_params(model, config["compression"], seed)
+    model, params, par = build_model_and_params(cell, seed, devices)
     jax.block_until_ready(params)
     marks.append(("weights", time.perf_counter()))
     observer = loadgen.make_observer(traced)
-    eng = build_engine(model, params, config, seed, observer)
+    eng = build_engine(model, params, config, seed, observer, par)
     if fault is not None:
         fault(eng)
     vocab = model.cfg.vocab_size
@@ -124,7 +161,9 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     stamp = {}
     win = loadgen.drive(eng, cell.traffic, requests, seconds, traced,
                        trace_dir, lambda t: stamp.setdefault("w", t))
-    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in devices]
+    peak = max(peaks) if None not in peaks else None
     setup_s = stamp["w"] - t_process
     in_window = clock.count(win.start, win.end)
 
@@ -184,6 +223,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         "reference_s": t_ref,
         "control": control,
         "compiles_in_window": in_window,
+        "memory_peak_bytes_by_device": peaks,
         "preemptions": sched["preempt_count"],
         "requests_finished": len(done),
         "compared_requests": len(sample),

@@ -16,24 +16,14 @@ that pick is scored and judged exactly as a served token is (``verdict``).
 
 from __future__ import annotations
 
-import importlib.util
 from typing import List, Tuple
 
 import numpy as np
 
-from .spec import BENCH
+from .spec import load_module
 
 SAMPLE_REQUESTS = 6     # requests compared per run, the longest among them
 PAD_TO = 256            # sequence lengths round up to this (fewer shapes)
-
-
-def reference_module(config: dict):
-    name = config["reference"]
-    path = BENCH / "reference" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_ref_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def draw_sample(done: List[Tuple[np.ndarray, list]], seed: int,
@@ -73,7 +63,7 @@ def pack(sample):
 
 def served_gaps(params, config: dict, sample) -> np.ndarray:
     """Reference best logit minus the served token's, per served token."""
-    ref = reference_module(config)
+    ref = load_module("reference", config["reference"])
     tokens, want = pack(sample)
     best, picked, _ = ref.forward(params, config, tokens,
                                   np.maximum(want, 0)[..., None])
@@ -85,7 +75,7 @@ def control_gaps(params, config: dict, sample) -> np.ndarray:
     """The control in the program's place: at each served position of the
     same prompts and served tokens, the reference's best logit minus its
     logit of the token the int8 reference puts first."""
-    ref = reference_module(config)
+    ref = load_module("reference", config["reference"])
     tokens, want = pack(sample)
     _, _, arg = ref.forward(params, config, tokens,
                             np.zeros(tokens.shape + (1,), np.int32),

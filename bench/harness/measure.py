@@ -31,10 +31,14 @@ def dispatches(run) -> List[tuple]:
 
 
 def paged_attention_least_s(run) -> Optional[float]:
-    """Least time of every paged-attention call in the trace: each call's
-    FLOPs and bytes from the live rows and cached tokens of the traced
-    window's dispatches (mean over them), KV read from HBM, the query and
-    output counted only where the call's HLO places them outside VMEM."""
+    """Least time of the paged-attention calls in the trace: the work one
+    chip needs for the whole batch, once per step and layer.  Each
+    dispatch's FLOPs and bytes come from its live rows and cached tokens
+    (mean over the traced window's dispatches), KV read from HBM, the query
+    and output counted only where the call's HLO places them outside VMEM.
+    Every device of a mesh runs one call per step and layer, each on its
+    own shard, so the calls of one step count once: len(calls) / devices.
+    Work a mesh repeats across its shards then shows as a lower share."""
     calls = run.trace.kernels.get("paged_attention", [])
     ds = dispatches(run)
     if not calls or not ds:
@@ -49,7 +53,7 @@ def paged_attention_least_s(run) -> Optional[float]:
             cfg.head_dim, act_bytes=2 if act_in_hbm else 0)
         per.append(roofline.least_time(c["flops"], c["bytes"],
                                        run.peaks)[0])
-    return len(calls) * float(np.mean(per))
+    return len(calls) / run.trace.devices * float(np.mean(per))
 
 
 _ITEMSIZE = {"bf16": 2, "f16": 2, "f32": 4, "s8": 1, "u8": 1, "s32": 4}
@@ -99,7 +103,9 @@ def useful_flops(run) -> float:
     window (linear layers and causal attention over its own tokens, the
     head once).  Padding rows of a prefill chunk do not count."""
     cfg = run.model_cfg
-    lin = roofline.linear_flops_per_token(run.factored_rows)
+    lin = roofline.linear_flops_per_token(
+        run.factored_rows, cfg.moe.top_k if cfg.moe else 0,
+        run.cell.config["deployment"].get("experts_published"))
     head = roofline.head_flops(cfg.d_model, cfg.vocab_size)
 
     def attn(keys):

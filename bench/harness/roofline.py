@@ -11,6 +11,8 @@ so a time derived from them is a lower bound and a share of it cannot pass
 
 from __future__ import annotations
 
+from typing import Optional
+
 # Published per-chip peaks, keyed by the ``device_kind`` JAX reports.
 # Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
 # 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip
@@ -64,15 +66,24 @@ def nested_lowrank_cost(rows: int, d_in: int, d_out: int, k1: int, k2: int,
     return {"flops": flops, "bytes": nbytes}
 
 
-def linear_flops_per_token(factored_rows) -> int:
+def linear_flops_per_token(factored_rows, top_k: int = 0,
+                           experts_published: Optional[int] = None):
     """Multiply-adds (x2) of every factored linear of every layer for one
-    token; ``factored_rows`` as ``weights.factored_rows`` gives them."""
+    token; ``factored_rows`` as ``weights.factored_rows`` gives them.  A
+    token runs only its ``top_k`` routed experts, of the
+    ``experts_published`` that a deployment spreads over its chips (by
+    default the experts held here): of a stack of E held experts it runs
+    top_k x E / experts_published on this chip.  Shared experts and every
+    other linear run whole."""
     total = 0
-    for _, d_in, d_out, k1, k2, stacked in factored_rows:
+    for row in factored_rows:
         n = 1
-        for s in stacked:
+        for s in row.stacked:
             n *= s
-        total += 2 * n * (d_in + d_out) * (k1 + k2)
+        if row.experts:
+            held = row.stacked[-1]
+            n = n // held * top_k * held / (experts_published or held)
+        total += 2 * n * (row.in_dim + row.out_dim) * (row.k1 + row.k2)
     return total
 
 

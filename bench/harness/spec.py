@@ -1,14 +1,21 @@
 """What one cell is: its entry in BENCHMARK.json, its configuration file,
-its traffic file, and the per-layer metric readers it reports.
+its traffic file, the builder of its model, and the per-layer metric
+readers it reports.
 
 Everything is found by name: a configuration ``<name>`` is
-``bench/configs/<name>.json``, a traffic mix ``<name>`` is
+``bench/configs/<name>.json``; the model it describes is built by
+``bench/builders/<builder>.py``, the file's ``"builder"`` (``llama`` where
+it names none), whose ``model_config(config)`` returns the program's
+``ModelConfig``; a traffic mix ``<name>`` is
 ``bench/traffic/<name>.json`` and a per-layer metric ``<name>`` is read by
 ``bench/metrics/<name>.py``, or, where that file does not exist, by the
 reader of the name's part before its first dot: ``mfu.decode`` and
 ``mfu.docs`` (one quantity, split by the end-to-end metric it moves) share
-``bench/metrics/mfu.py``.  Adding a cell adds files and a ``workloads``
-entry; no code here changes.
+``bench/metrics/mfu.py``.  The configuration's ``deployment`` may set
+``dp`` and ``tp`` (1 and 1 by default): the program serves the cell on a
+(dp, tp) mesh, and dp x tp has to be the cell's ``chips``.  Adding a cell,
+of a new model family too, adds files and a ``workloads`` entry; no code
+here changes.
 """
 
 from __future__ import annotations
@@ -46,6 +53,16 @@ class Cell:
     end_to_end: tuple
     per_layer: tuple
 
+    def mesh(self) -> tuple:
+        """(dp, tp) of the configuration's deployment; ValueError where
+        their product is not the cell's chips."""
+        dep = self.config["deployment"]
+        dp, tp = int(dep.get("dp", 1)), int(dep.get("tp", 1))
+        if dp < 1 or tp < 1 or dp * tp != self.chips:
+            raise ValueError(f"{self.name}: deployment dp {dp} x tp {tp} "
+                             f"does not make the cell's {self.chips} chips")
+        return dp, tp
+
 
 def load_benchmark() -> dict:
     return json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -77,49 +94,38 @@ def load_cell(name: str) -> Cell:
     w = by_name[name]
     cfg_entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = json.loads((ROOT / cfg_entry["file"]).read_text())
-    return Cell(name, config, load_json("traffic", w["traffic"]),
+    cell = Cell(name, config, load_json("traffic", w["traffic"]),
                 int(w["chips"]), _metrics(bench["end_to_end"], name),
                 _metrics(bench["per_layer"], name))
+    cell.mesh()     # refuses a mesh that is not the cell's chips
+    return cell
+
+
+def load_module(kind: str, name: str):
+    """``bench/<kind>/<name>.py``, loaded as a module."""
+    path = BENCH / kind / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no {kind} file {path}")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def model_config(config: dict):
-    """The program's ModelConfig for a configuration file (a Llama-style
-    decoder: RMSNorm, RoPE, GQA, SwiGLU, untied output head)."""
-    from repro.configs.base import ModelConfig
-
-    act = config["hidden_act"]
-    if act != "silu":
-        raise ValueError(f"{config['name']}: hidden_act {act!r}, not silu")
-    return ModelConfig(
-        name=config["name"], family="dense",
-        num_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"],
-        vocab_size=config["vocab_size"],
-        head_dim=config["head_dim"],
-        attention="gqa", pos_emb="rope", rope_theta=config["rope_theta"],
-        norm="rmsnorm", activation="swiglu",
-        tie_embeddings=config["tie_word_embeddings"],
-        max_seq=config["max_position_embeddings"],
-        dtype=config["torch_dtype"],
-    )
+    """The program's ModelConfig for a configuration file, from the
+    builder the file names."""
+    builder = load_module("builders", config.get("builder", "llama"))
+    return builder.model_config(config)
 
 
 def metric_reader(name: str) -> Callable:
     """``read(run)`` of the metric's reader; it returns a number, or None
     where the run holds nothing for it to read."""
-    path = BENCH / "metrics" / f"{name}.py"
-    if not path.is_file():
-        path = BENCH / "metrics" / f"{name.split('.', 1)[0]}.py"
-    if not path.is_file():
-        raise FileNotFoundError(f"no reader {path} for metric {name!r}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{path.stem}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    if not (BENCH / "metrics" / f"{name}.py").is_file():
+        name = name.split(".", 1)[0]
+    return load_module("metrics", name).read
 
 
 def read_metrics(metrics, run) -> Dict[str, dict]:

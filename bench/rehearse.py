@@ -64,7 +64,7 @@ def rehearse(name: str, one_chip, hbm_bytes: float) -> dict:
                                            sharding=one_chip), tree)
 
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        for path, d_in, d_out, k1, k2, _ in weights.factored_rows(
+        for path, d_in, d_out, k1, k2, *_ in weights.factored_rows(
                 model, config["compression"]):
             for rows in (dep["max_batch"],
                          dep["max_batch"] * ctx.prefill_chunk):

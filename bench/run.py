@@ -5,16 +5,19 @@
 
 From the root of a checkout.  It builds the cell's configuration with
 factored weights drawn on the device from ``--seed``, builds the program's
-``ServingEngine`` with the configuration's deployment settings, warms up
+``ServingEngine`` with the configuration's deployment settings (on a
+(dp, tp) mesh over the cell's chips where it sets one), warms up
 the shapes the cell uses, offers the cell's traffic for a lead-in and then
 for ``--seconds`` measured seconds, and checks the served tokens against
 the plain reference.  With ``--trace 0`` the result's metrics are the
 cell's end-to-end metrics; with ``--trace 1`` its per-layer metrics, read
 from a profiler trace of the window's start and the engine's counters.
 
-Without an accelerator, or with fewer chips than the cell asks for, it
-exits nonzero before printing any result.  The last lines of stderr, and
-the last key of the result line, give each number compared with its limit.
+Without an accelerator, with fewer chips than the cell asks for, or with
+a deployment whose dp x tp is not the cell's chips, it exits nonzero
+before building anything or printing any result.  The last lines of
+stderr, and the last key of the result line, give each number compared
+with its limit.
 """
 
 from __future__ import annotations
@@ -62,7 +65,11 @@ def main(argv=None) -> int:
     if not (ROOT / "BENCHMARK.json").is_file():
         print(f"bench: no BENCHMARK.json at {ROOT}", file=sys.stderr)
         return 2
-    cell = spec.load_cell(args.workload)
+    try:
+        cell = spec.load_cell(args.workload)
+    except ValueError as e:     # a deployment mesh that is not its chips
+        print(f"bench: {e}", file=sys.stderr)
+        return 2
     if not (ROOT / "src" / "repro").is_dir():
         print(f"bench: no program at {ROOT / 'src'}", file=sys.stderr)
         return 2

@@ -39,28 +39,26 @@ def main(argv=None) -> int:
 
     import numpy as np
 
-    from harness import cell_run, loadgen, spec, traffic, weights
+    from harness import cell_run, loadgen, spec, traffic
     from run import require_chips
 
     cell = spec.load_cell(args.workload)
     if cell.traffic["loop"] != "open":
         raise SystemExit("sweep: only an open-loop cell has a rate")
-    require_chips(cell.chips)
+    devices = require_chips(cell.chips)[: cell.chips]
     import jax
 
     from repro.launch.compile_cache import enable_compilation_cache
-    from repro.models import build_model
 
     enable_compilation_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    model = build_model(spec.model_config(cell.config))
-    params = weights.build_params(model, cell.config["compression"],
-                                  args.seed)
+    model, params, par = cell_run.build_model_and_params(cell, args.seed,
+                                                         devices)
     vocab = model.cfg.vocab_size
     for rate in (float(r) for r in args.rates.split(",")):
         mix = dict(cell.traffic, rate_per_s=rate)
         eng = cell_run.build_engine(model, params, cell.config, args.seed,
-                                    loadgen.make_observer(False))
+                                    loadgen.make_observer(False), par)
         cell_run.warm_up(eng, vocab)
         requests = traffic.generate(mix, args.seed, args.seconds, vocab)
         drv = loadgen.LoadGen(eng, mix, requests)

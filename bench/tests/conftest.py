@@ -4,6 +4,7 @@
 """
 
 import json
+import shutil
 import sys
 from pathlib import Path
 
@@ -37,3 +38,19 @@ def tiny_mix(name: str) -> dict:
     else:
         mix["clients"] = 3
     return mix
+
+
+@pytest.fixture
+def bench_copy(tmp_path, monkeypatch):
+    """A copy of the benchmark's data, builders and readers under a
+    temporary root, which the harness then reads."""
+    from harness import spec
+
+    root = tmp_path / "repo"
+    bench = root / "bench"
+    for sub in ("configs", "traffic", "metrics", "builders"):
+        shutil.copytree(spec.BENCH / sub, bench / sub)
+    shutil.copy(spec.ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
+    monkeypatch.setattr(spec, "BENCH", bench)
+    monkeypatch.setattr(spec, "ROOT", root)
+    return root

@@ -2,25 +2,11 @@
 and a new metric file take effect with no code edited."""
 
 import json
-import shutil
 
 import numpy as np
 import pytest
 
 from harness import spec, traffic
-
-
-@pytest.fixture
-def bench_copy(tmp_path, monkeypatch):
-    """A copy of the benchmark's data under a temporary root."""
-    root = tmp_path / "repo"
-    bench = root / "bench"
-    for sub in ("configs", "traffic", "metrics"):
-        shutil.copytree(spec.BENCH / sub, bench / sub)
-    shutil.copy(spec.ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
-    monkeypatch.setattr(spec, "BENCH", bench)
-    monkeypatch.setattr(spec, "ROOT", root)
-    return root
 
 
 def test_a_new_traffic_file_and_metric_file_are_picked_up(bench_copy):

@@ -38,7 +38,10 @@ def test_unknown_device_kind_is_an_error():
 
 
 def test_token_flops_by_hand():
-    rows = [(("a",), 8, 4, 3, 1, (2,)), (("b",), 4, 8, 2, 0, ())]
+    from harness.weights import FactoredRow
+
+    rows = [FactoredRow(("a",), 8, 4, 3, 1, (2,)),
+            FactoredRow(("b",), 4, 8, 2, 0, ())]
     # 2 layers x 2 * (8 + 4) * 4, plus 2 * (4 + 8) * 2.
     assert roofline.linear_flops_per_token(rows) == 2 * 2 * 12 * 4 + 2 * 12 * 2
     assert roofline.attention_flops(2, 4, 8, 10) == 4 * 2 * 4 * 8 * 10
